@@ -201,6 +201,7 @@ def run_grid(grid, *, iters: int, out_path: str = ARTIFACT,
     from repro.models import build_model
     from repro.models.transformer import ModelOptions
     from repro.optim.adamw import init_train_state
+    from repro.parallel.compat import make_mesh
     from repro.train.loop import TrainConfig
     from repro.train.pipeline_loop import make_pipeline_train_step
     from repro.train.schedules import build_exec_tables, make_schedule
@@ -239,7 +240,7 @@ def run_grid(grid, *, iters: int, out_path: str = ARTIFACT,
     scale_by_cell: Dict[tuple, float] = {}
     for (schedule, n_chunks, pp, dp, tp, sp, ep, zero) in grid:
         n_micro = n_micro_for(pp)
-        mesh = jax.make_mesh((pp, dp, tp), ("pipe", "data", "model"))
+        mesh = make_mesh((pp, dp, tp), ("pipe", "data", "model"))
         step = jax.jit(make_pipeline_train_step(
             model, TrainConfig(n_micro=n_micro), mesh,
             schedule=schedule, n_chunks=n_chunks, zero=zmap[zero],

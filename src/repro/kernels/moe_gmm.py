@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gmm_kernel(expert_map_ref, lhs_ref, rhs_ref, out_ref):
@@ -45,27 +46,21 @@ def gmm_pallas(lhs: jnp.ndarray, rhs: jnp.ndarray, expert_map: jnp.ndarray,
     assert N % bn == 0
     grid = (M // block_m, N // bn)
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, K), lambda i, j, emap: (i, 0)),
-                pl.BlockSpec((None, K, bn), lambda i, j, emap: (emap[i], 0, j)),
-            ],
-            out_specs=pl.BlockSpec((block_m, bn), lambda i, j, emap: (i, j)),
-        )
-        return pl.pallas_call(
-            _gmm_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-            interpret=interpret,
-        )(expert_map, lhs, rhs)
-    except (ImportError, NotImplementedError):
-        # portable fallback grid spec (no scalar prefetch): pass the map as
-        # a regular SMEM operand
-        raise
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((block_m, K), lambda i, j, emap: (i, 0)),
+            pl.BlockSpec((None, K, bn), lambda i, j, emap: (emap[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_m, bn), lambda i, j, emap: (i, j)),
+    )
+    return pl.pallas_call(
+        _gmm_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        interpret=interpret,
+    )(expert_map, lhs, rhs)
 
 
 def pad_groups(x: jnp.ndarray, group_sizes: np.ndarray, block_m: int

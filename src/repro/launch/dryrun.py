@@ -656,14 +656,6 @@ def main() -> int:
                          "chunk layout and in-flight residency (zb1p: 1f1b "
                          "activation residency + the fp32 pending-dW stash "
                          "in the analytic grads column)")
-    ap.add_argument("--bench-steps", type=int, default=None, metavar="ITERS",
-                    help="run the measured step-time benchmark instead of "
-                         "compile probes: benchmarks/step_bench.py grid "
-                         "(schedule x pp on the 8-fake-device mesh), "
-                         "ITERS timed windows per config, rows appended "
-                         "newest-wins to benchmarks/artifacts/"
-                         "BENCH_step.json; spawned as a subprocess so its "
-                         "device count is independent of this dry-run's")
     ap.add_argument("--pp-chunks", type=int, default=None,
                     help="virtual stages per rank (interleaved: >=2; "
                          "defaults to 2 for interleaved/dualpipe)")
@@ -674,14 +666,6 @@ def main() -> int:
                     help="override per-pod grid, e.g. 32x8")
     ap.add_argument("--tag-suffix", default="")
     args = ap.parse_args()
-    if args.bench_steps is not None:
-        import subprocess
-        bench = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                             "benchmarks", "step_bench.py")
-        cmd = [sys.executable, os.path.abspath(bench),
-               "--iters", str(args.bench_steps)]
-        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-        return subprocess.call(cmd, env=env)
     mesh_shape = tuple(int(x) for x in args.mesh_shape.split("x")) \
         if args.mesh_shape else None
     if args.tp:

@@ -12,9 +12,8 @@ state (the dry-run must set XLA_FLAGS before first jax use).
 
 from __future__ import annotations
 
-import jax
-
 from repro.core.parallel_config import ParallelConfig, ZeROStage
+from repro.parallel.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None, pp: int = 1):
@@ -36,14 +35,14 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None, pp: int = 1):
         grid, axes = (data, model), ("data", "model")
     if multi_pod:
         grid, axes = (2,) + grid, ("pod",) + axes
-    return jax.make_mesh(grid, axes)
+    return make_mesh(grid, axes)
 
 
 def make_debug_mesh(model: int = 1, data: int = 1, pipe: int = 1):
     """Tiny mesh over however many (possibly fake) local devices exist."""
     if pipe > 1:
-        return jax.make_mesh((pipe, data, model), ("pipe", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pipe, data, model), ("pipe", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def parallel_config_for_mesh(mesh, *, spec=None, zero: ZeROStage = ZeROStage.OS_G,

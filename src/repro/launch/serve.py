@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_spec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.transformer import ModelOptions
 from repro.serving import ServeConfig, serve_requests
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--cache-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_spec(args.arch, smoke=args.smoke)
     model = build_model(spec, ModelOptions())

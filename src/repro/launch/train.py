@@ -24,11 +24,13 @@ import jax.numpy as jnp
 from repro.configs import get_spec
 from repro.core.parallel_config import RecomputePolicy, ZeROStage
 from repro.data.synthetic import config_for, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import batch_shardings
 from repro.models import build_model
 from repro.models.transformer import ModelOptions
 from repro.optim.adamw import AdamWConfig, init_train_state
 from repro.parallel.axes import axis_rules
+from repro.parallel.compat import make_mesh
 from repro.parallel.sharding import state_shardings
 from repro.train.loop import TrainConfig, make_train_step
 
@@ -53,6 +55,7 @@ def main():
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_spec(args.arch, smoke=args.smoke)
     opts = ModelOptions(attn_impl=args.attn,
@@ -61,7 +64,7 @@ def main():
 
     n_dev = jax.device_count()
     data_ax = args.data_axis or (n_dev // args.model_axis)
-    mesh = jax.make_mesh((data_ax, args.model_axis), ("data", "model"))
+    mesh = make_mesh((data_ax, args.model_axis), ("data", "model"))
     print(f"arch={spec.name} devices={n_dev} mesh=({data_ax},{args.model_axis}) "
           f"zero={args.zero} ac={args.recompute}")
 

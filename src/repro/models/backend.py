@@ -211,13 +211,21 @@ def mla_attention(q, k, v, *, scale: float, impl: str = "naive"):
 # grouped MLP (the MoE expert FFN over the static-capacity dispatch buffer)
 # ---------------------------------------------------------------------------
 
-def _gmm_block(n: int, pref: int = 128) -> int:
+def _gmm_block(n: int, *, rows: bool, pref: int = 128) -> int:
     """Block size for one GEMM dim: the MXU-friendly 128 when it divides,
-    else the whole dim as a single tile (always valid — the dispatch
-    buffer's capacity/ff dims are static; a giant single tile only costs
-    VMEM on real TPUs, where capacity_factor should be chosen so C, f and
-    h are multiples of 128)."""
-    return pref if n % pref == 0 else n
+    else the whole dim as one tile.  A whole column tile (f or h) is
+    always legal — it spans the array's full last dim.  A row block (the
+    capacity C) is one of E·C rows, so Mosaic needs it 8-aligned; pick
+    ``capacity_factor``, b and s so that C is a multiple of 128 (or at
+    least 8) — anything else is refused here, before lowering."""
+    if n % pref == 0:
+        return pref
+    if rows and n % 8:
+        raise ValueError(
+            f"grouped_mlp: capacity C={n} is neither a multiple of {pref} "
+            "nor 8-aligned, so the Pallas row block cannot be C itself; "
+            "choose capacity_factor, batch and seq so C % 128 == 0")
+    return n
 
 
 @jax.custom_vjp
@@ -225,11 +233,11 @@ def _pallas_grouped_mlp(buf, wg, wu, wd):
     from repro.kernels import ops as K
     E, C, h = buf.shape
     f = wg.shape[-1]
-    bm = _gmm_block(C)
+    bm = _gmm_block(C, rows=True)
     # rows are pre-grouped C-per-expert, so the expert map is static
     emap = jnp.repeat(jnp.arange(E, dtype=jnp.int32), C // bm)
     lhs = buf.reshape(E * C, h)
-    bn_f, bn_h = _gmm_block(f), _gmm_block(h)
+    bn_f, bn_h = _gmm_block(f, rows=False), _gmm_block(h, rows=False)
     gate = K.gmm(lhs, wg, emap, block_m=bm, block_n=bn_f)
     up = K.gmm(lhs, wu, emap, block_m=bm, block_n=bn_f)
     a = jax.nn.silu(gate) * up

@@ -114,6 +114,7 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
                 sp_axis: Optional[str] = None,
                 ep: int = 1,
                 ep_axis: Optional[str] = None,
+                dp_axes: Tuple[str, ...] = (),
                 backend: str = "reference") -> MoEOutput:
     """x: (b, s, h) -> (b, s, h).
 
@@ -151,7 +152,14 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     on the ETP path (``tp_f``/``tp_g``, every token), and the router —
     consumed inside the token-sharded region — accumulates token-partial
     gradients the executor completes with its post-loop 'model' psum
-    (the same completion SP already requires)."""
+    (the same completion SP already requires).
+
+    ``dp_axes`` names the data-parallel mesh axes whose shards hold the
+    other samples of the same microbatch.  The load-balance means are
+    combined across them too, so ``aux_loss`` is the whole-microbatch value
+    of the unsharded step (a per-shard aux would be a mean of products, not
+    the product of the means).  The router gradient this leaves is
+    data-partial, which the executor's post-loop data psum completes."""
     e = spec.moe
     b, s, h = x.shape
     T = b * s
@@ -167,7 +175,7 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
         return _moe_forward_ep(p, spec, x, capacity_factor=capacity_factor,
                                router_impl=router_impl, tp_f=tp_f, tp_g=tp_g,
                                sp_axis=sp_axis, ep=ep, ep_axis=ep_axis,
-                               backend=backend)
+                               dp_axes=dp_axes, backend=backend)
 
     probs, gates, eids = _route(p["router"], spec, xt, router_impl)
 
@@ -175,9 +183,10 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     me = jnp.mean(probs, axis=0)
     ce = jnp.mean(
         (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
-    if sp_axis is not None:
+    stat_axes = tuple(dp_axes) + ((sp_axis,) if sp_axis is not None else ())
+    if stat_axes:
         from repro.parallel.tp import pmean_sp
-        me, ce = pmean_sp(me, sp_axis), pmean_sp(ce, sp_axis)
+        me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
     aux = E * jnp.sum(me * ce)
 
     C = int(max(1, round(T * K / E * capacity_factor)))
@@ -218,6 +227,7 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
                     capacity_factor: float, router_impl: str,
                     tp_f, tp_g, sp_axis: Optional[str],
                     ep: int, ep_axis: str,
+                    dp_axes: Tuple[str, ...] = (),
                     backend: str = "reference") -> MoEOutput:
     """True expert parallelism inside the manual-collectives executor
     (paper §3.3): weights sharded ``(E/ep, h, h_E)`` on the expert dim over
@@ -257,7 +267,8 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     me = jnp.mean(probs, axis=0)
     ce = jnp.mean(
         (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
-    me, ce = pmean_sp(me, ep_axis), pmean_sp(ce, ep_axis)
+    stat_axes = tuple(dp_axes) + (ep_axis,)
+    me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
     aux = E * jnp.sum(me * ce)
 
     tk = t_loc * K

@@ -398,7 +398,8 @@ def unstack_pipeline_grads(gstack: PyTree, params: PyTree, spec: ModelSpec,
 def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
                 x: jnp.ndarray, positions: jnp.ndarray, mask: jnp.ndarray,
                 moe_flag: jnp.ndarray, tp_axis: Optional[str] = None,
-                sp: bool = False, ep: int = 1
+                sp: bool = False, ep: int = 1,
+                dp_axes: Tuple[str, ...] = ()
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One union layer slot.  ``mask`` (scalar f32) turns pad slots into the
     identity; ``moe_flag`` selects the MoE vs dense-MLP branch when the model
@@ -424,7 +425,10 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
     their *expert* dim and the dispatch is ``moe_forward``'s all-to-all
     token exchange instead of the replicated ETP buffer — ``tp_f``/``tp_g``
     then only bracket the shared expert (still ETP-sharded on its ff
-    dim)."""
+    dim).
+
+    ``dp_axes`` (the executor's data-parallel axes) makes the MoE aux loss
+    the whole-microbatch value (``moe_forward``'s ``dp_axes``)."""
     from repro.parallel.tp import (copy_to_tp, gather_from_sp,
                                    reduce_from_tp, scatter_to_sp)
     gemma = spec.name.startswith("gemma")
@@ -480,7 +484,7 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
                           tp_g=tpg if tp_axis else None,
                           sp_axis=tp_axis if sp else None,
                           ep=ep, ep_axis=tp_axis if ep > 1 else None,
-                          backend=backend)
+                          dp_axes=dp_axes, backend=backend)
         sel = moe_flag.astype(x.dtype)
         delta = out.y * sel
         if has_mlp:
@@ -500,13 +504,15 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
                          moe_flag: jnp.ndarray,
                          tp_axis: Optional[str] = None,
                          sp: bool = False, ep: int = 1,
-                         remat: bool = True
+                         remat: bool = True,
+                         dp_axes: Tuple[str, ...] = ()
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scan this stage's l_max union slots.  ``layers_p`` leaves are
     (l_max, ...); ``mask``/``moe_flag`` are (l_max,).  With ``tp_axis`` the
     slots run manual TP; with ``sp`` additionally Megatron sequence
     parallelism — ``x`` is then the seq-sharded residual; with ``ep`` the
-    MoE slots dispatch expert-parallel over the same axis (see
+    MoE slots dispatch expert-parallel over the same axis; ``dp_axes``
+    combines the MoE load-balance statistics across the data shards (see
     ``_slot_apply``).
 
     ``remat=False`` bypasses ``opts.recompute`` for this call: a vjp through
@@ -520,7 +526,7 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
         xc, aux = carry
         p_slot, m, f = inp
         xc, a = _slot_apply(p_slot, spec, opts, xc, positions, m, f, tp_axis,
-                            sp, ep)
+                            sp, ep, dp_axes)
         return (xc, aux + a), None
 
     if remat:

@@ -34,7 +34,7 @@ scales, the MoE router) see only their shard's tokens, so their local
 gradients are seq-partial and the executor completes them with one
 ``psum`` over 'model' after the tick loop (``train.pipeline_loop``).
 
-Why not plain ``jax.lax.psum``: under ``shard_map(check_rep=False)`` jax
+Why not plain ``jax.lax.psum``: under ``shard_map(check_vma=False)`` jax
 cannot prove replication, so it transposes ``psum`` to another ``psum`` —
 weight gradients come out ``tp``× too large.  The custom-vjp pairs encode
 the replication facts we know by construction.  With f/g placed at every
@@ -169,9 +169,10 @@ scatter_to_sp.defvjp(_scatter_sp_fwd, _scatter_sp_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def pmean_sp(x: jnp.ndarray, axis: str = TP_AXIS) -> jnp.ndarray:
     """Cross-shard mean of per-shard token statistics (the MoE router's
-    load-balance means under SP, where each shard routes a disjoint seq
-    chunk).  Forward ``pmean``; backward hands each shard ``ct / sp`` —
-    the exact chain factor ∂mean/∂(shard summand), with no psum because
+    load-balance means under SP, EP or DP, where each shard routes a
+    disjoint token chunk; ``axis`` may be a tuple of axes).  Forward
+    ``pmean``; backward hands each shard ``ct / sp`` — the exact chain
+    factor ∂mean/∂(shard summand), with no psum because
     the downstream consumer (the aux loss) is replicated, so every shard
     already carries the identical cotangent.  The seq-partial router
     gradients this produces are completed by the executor's post-loop
@@ -206,7 +207,7 @@ def gather_params(x: jnp.ndarray, axes=("data",), dim: int = 0) -> jnp.ndarray:
     contributions (the job the executor's post-loop data psum does for
     replicated leaves) and (b) re-shards the result onto the owner —
     so gradients, like the ZeRO-2 spec requires, only ever materialize
-    shard-sized.  Same check_rep=False rationale as f/g: a plain
+    shard-sized.  Same check_vma=False rationale as f/g: a plain
     all_gather would transpose to psum_scatter of *already-summed*
     cotangents only if jax could prove the forward input was unreplicated
     per-shard data, which it can't here."""
@@ -236,7 +237,7 @@ gather_params.defvjp(_gather_params_fwd, _gather_params_bwd)
 # no extra operator; without SP the residual is replicated across 'model'
 # and the EP region is bracketed by this pair — the token-dim analogue of
 # copy_to_tp / reduce_from_tp, encoding the same replication facts the
-# check_rep=False shard_map cannot prove:
+# check_vma=False shard_map cannot prove:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def shard_tokens_ep(x: jnp.ndarray, axis: str = TP_AXIS,

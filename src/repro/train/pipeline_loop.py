@@ -170,10 +170,10 @@ exactly as under SP.  The a2a dispatch group is the whole 'model' axis,
 so the executor ties ``ep`` to ``tp`` (``parallel.tp.check_ep_supported``;
 grouped sub-axis a2a remains estimator-only).
 
-Scope notes: MoE aux uses the capacity dispatch and is pmean'd across
-data shards (and, under ``sp``/``ep``, its load-balance means are
-combined across the token shards so the aux value matches the
-unsharded step exactly).
+Scope notes: MoE aux uses the capacity dispatch; its load-balance means
+are combined across the data shards (and, under ``sp``/``ep``, across
+the token shards) so the aux value matches the unsharded step exactly.
+The capacity itself stays per data shard, as in a real deployment.
 """
 
 from __future__ import annotations
@@ -409,7 +409,8 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b_loc, s))
             y, aux = pipeline_stage_apply(pl, spec_run, opts, x, positions,
                                           smask[c], sflag[c], tp_axis,
-                                          sp=sp, ep=ep, remat=remat)
+                                          sp=sp, ep=ep, remat=remat,
+                                          dp_axes=data_axes)
             z = B.rmsnorm(ps["final_norm"], y, spec.norm_eps,
                           gemma_style=gemma, backend=B.resolve_backend(opts))
             w_out = ps["embed"]["w"].T if spec.tie_embeddings \
@@ -451,13 +452,14 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             """Output cotangents of ``chunk_fn`` for retiring chunk ``c``:
             the boundary grad ``dy`` (zeroed on the last model chunk, whose
             ``y`` has no consumer), the CE mean cotangent (nonzero only on
-            the last chunk) and the 0.01 aux weight (aux is a per-data-shard
-            mean whose loss term is the pmean, so each shard carries
-            1/data_size; grads are psummed over the data axes below)."""
+            the last chunk) and the 0.01 aux weight (aux is the
+            whole-microbatch value on every data shard; its backward hands
+            each shard 1/data_size of the load-balance cotangent, and the
+            grads are psummed over the data axes below)."""
             lastc = last_l[c]
             dy_cot = jnp.where(lastc < 0.5, dy, jnp.zeros((), dy.dtype))
             dce = lastc / jnp.maximum(count_g(tok, mm), 1.0)
-            return dy_cot, dce, jnp.float32(0.01 / data_size)
+            return dy_cot, dce, jnp.float32(0.01)
 
         def tick(carry, t):
             if zb:
@@ -678,7 +680,6 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 g, gdims_g)
         else:
             g = jax.tree.map(lambda a: _psum(a, data_axes)[None], g)
-        aux_acc = jax.lax.pmean(aux_acc, data_axes) if data_axes else aux_acc
         loss_sum = jax.lax.psum(loss + 0.01 * aux_acc, "pipe")
         return g, loss_sum
 

@@ -63,6 +63,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.data.synthetic import config_for, make_batch
@@ -101,7 +102,7 @@ FAST = HEADER + textwrap.dedent("""
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
     batch = make_batch(config_for(spec, 8, 32), 0)
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                     zero=ZeROStage.OS, ep=2)
     s2, m2 = jax.jit(step)(state, batch)
@@ -129,13 +130,14 @@ DENSE_REF = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import functools
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.configs import get_spec
     from repro.models.moe import moe_forward, moe_forward_dense_ref, moe_init
     from repro.parallel.compat import shard_map
 
     spec = get_spec("olmoe-1b-7b", smoke=True)      # 4 experts top-2
-    mesh = jax.make_mesh((2,), ("model",))
+    mesh = make_mesh((2,), ("model",))
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32),
                        moe_init(jax.random.PRNGKey(0), spec))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, spec.h), jnp.float32)
@@ -182,7 +184,7 @@ GRID_BODY = textwrap.dedent("""
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
     meshes = [(1, 2, 2), (2, 2, 2)] if SCHEDULE == "1f1b" else [(2, 2, 2)]
     for pp, data, tp in meshes:
-        mesh = jax.make_mesh((pp, data, tp), ("pipe", "data", "model"))
+        mesh = make_mesh((pp, data, tp), ("pipe", "data", "model"))
         for sp in (False, True):
             step = make_pipeline_train_step(
                 model, TrainConfig(n_micro=4), mesh, schedule=SCHEDULE,
@@ -203,7 +205,7 @@ MOE_MLA_EP = HEADER + textwrap.dedent("""
     batch = make_batch(config_for(spec, 4, 32), 0)
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
     for sp in (False, True):
-        mesh = jax.make_mesh((2, 1, 2), ("pipe", "data", "model"))
+        mesh = make_mesh((2, 1, 2), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh,
                                         zero=ZeROStage.OS, sp=sp, ep=2)
         s2, m2 = jax.jit(step)(state, batch)

@@ -121,6 +121,22 @@ def test_grouped_mlp_equivalence_bf16():
         _assert_close(f"gmm {name}", gp, gr, ATOL_GRAD)
 
 
+def test_gmm_block_refuses_tiles_mosaic_refuses():
+    """128 when it divides; else the whole dim — always legal for the
+    column dims, legal for the capacity (row) dim only when 8-aligned."""
+    assert B._gmm_block(640, rows=True) == 128
+    assert B._gmm_block(40, rows=True) == 40
+    assert B._gmm_block(96, rows=False) == 96
+    assert B._gmm_block(5, rows=False) == 5
+    with pytest.raises(ValueError, match="capacity C=5"):
+        B._gmm_block(5, rows=True)
+    buf = jnp.zeros((4, 5, 64), jnp.bfloat16)
+    w = jnp.zeros((4, 64, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="C % 128 == 0"):
+        B.grouped_mlp(buf, w, w, jnp.zeros((4, 128, 64), jnp.bfloat16),
+                      backend="pallas")
+
+
 def test_unsupported_flash_request_warns_with_reason():
     """Satellite: the fallback is loud and names the reason — sliding
     window and non-causal both refuse the kernel."""
@@ -145,6 +161,7 @@ PALLAS_3D = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.data.synthetic import config_for, make_batch
     from repro.models import build_model
@@ -158,7 +175,7 @@ PALLAS_3D = textwrap.dedent("""
     m_pal = build_model(spec, ModelOptions(backend="pallas"))
     params = m_ref.init(jax.random.PRNGKey(0))
     batch = make_batch(config_for(spec, 8, 32), 0)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     s1, m1 = jax.jit(make_pipeline_train_step(
         m_ref, TrainConfig(n_micro=4), mesh))(init_train_state(params), batch)
     s2, m2 = jax.jit(make_pipeline_train_step(

@@ -16,13 +16,14 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.parallel.compat import make_mesh
     from jax.sharding import Mesh
     from repro.configs import get_spec
     from repro.models.moe import moe_forward, moe_init
     from repro.models.moe_a2a import moe_forward_a2a
 
     spec = get_spec("olmoe-1b-7b", smoke=True)   # 4 experts top-2
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     p = moe_init(jax.random.PRNGKey(0), spec)
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32), p)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, spec.h), jnp.float32)

@@ -15,6 +15,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.data.synthetic import config_for, make_batch
@@ -36,7 +37,7 @@ SCRIPT = textwrap.dedent("""
     s1, m1 = jax.jit(step)(state, batch)
 
     # 2x4 mesh, ZeRO os+g+params
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     abstract = jax.eval_shape(lambda: state)
     st_sh = state_shardings(abstract, mesh, ZeROStage.OS_G_PARAMS)
     b_sh = batch_shardings(jax.eval_shape(lambda: batch), mesh)

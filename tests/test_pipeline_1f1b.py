@@ -17,6 +17,7 @@ DENSE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.data.synthetic import config_for, make_batch
     from repro.models import build_model
@@ -37,7 +38,7 @@ DENSE_SCRIPT = textwrap.dedent("""
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
 
     for pp, data in [(2, 2), (4, 2)]:
-        mesh = jax.make_mesh((pp, data), ("pipe", "data"))
+        mesh = make_mesh((pp, data), ("pipe", "data"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                         schedule=SCHEDULE, n_chunks=N_CHUNKS)
         s2, m2 = jax.jit(step)(state, batch)
@@ -55,6 +56,7 @@ MOE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.data.synthetic import config_for, make_batch
     from repro.models import build_model
@@ -81,7 +83,7 @@ MOE_SCRIPT = textwrap.dedent("""
         state = init_train_state(model.init(jax.random.PRNGKey(0)))
         batch = make_batch(config_for(spec, 4, 32), 0)
         s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
-        mesh = jax.make_mesh((2, data), ("pipe", "data"))
+        mesh = make_mesh((2, data), ("pipe", "data"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh,
                                         schedule=SCHEDULE, n_chunks=N_CHUNKS)
         s2, m2 = jax.jit(step)(state, batch)

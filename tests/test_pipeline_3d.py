@@ -24,6 +24,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.data.synthetic import config_for, make_batch
@@ -50,7 +51,7 @@ DENSE_FAST = HEADER + textwrap.dedent("""
     batch["mask"] = jnp.broadcast_to(
         (jnp.arange(32) < 28).astype(jnp.float32)[None], (8, 32))
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                     zero=ZeROStage.OS)
     s2, m2 = jax.jit(step)(state, batch)
@@ -68,7 +69,7 @@ DENSE_GRID_BODY = textwrap.dedent("""
         (jnp.arange(32) < 28).astype(jnp.float32)[None], (8, 32))
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
     for pp, data, tp in [(2, 2, 2), (2, 1, 2), (4, 1, 2)]:
-        mesh = jax.make_mesh((pp, data, tp), ("pipe", "data", "model"))
+        mesh = make_mesh((pp, data, tp), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                         schedule=SCHEDULE, n_chunks=N_CHUNKS,
                                         zero=ZeROStage.OS)
@@ -93,11 +94,43 @@ MOE_TP = HEADER + textwrap.dedent("""
         state = init_train_state(model.init(jax.random.PRNGKey(0)))
         batch = make_batch(config_for(spec, 4, 32), 0)
         s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
-        mesh = jax.make_mesh((2, data, 2), ("pipe", "data", "model"))
+        mesh = make_mesh((2, data, 2), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh,
                                         zero=ZeROStage.OS)
         s2, m2 = jax.jit(step)(state, batch)
         check(f"{name}_TP2", m1, s1, m2, s2, tol_loss=tol)
+""")
+
+MOE_AUX_DP = HEADER + textwrap.dedent("""
+    # fp32 weights take bf16 rounding out of the comparison, and a dropless
+    # capacity takes out the per-shard capacity: what is left at dp2 is the
+    # aux loss, which must be the whole-microbatch value of the one-device
+    # step although each data shard routes only half the microbatch
+    from repro.models.transformer import ModelOptions
+    spec = dataclasses.replace(get_spec("deepseek-v3", smoke=True), n_layers=4)
+    model = build_model(spec, ModelOptions(capacity_factor=4.0))
+    params = jax.tree.map(lambda a: a.astype(jnp.float32),
+                          model.init(jax.random.PRNGKey(0)))
+    state = init_train_state(params)
+    batch = make_batch(config_for(spec, 4, 32), 0)
+    s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
+    mesh = make_mesh((2, 2, 1), ("pipe", "data", "model"),
+                     devices=jax.devices()[:4])
+    step = make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh,
+                                    zero=ZeROStage.OS)
+    s2, m2 = jax.jit(step)(state, batch)
+    dl = abs(float(m1["loss"]) - float(m2["loss"]))
+    assert dl < 1e-5, f"loss diverged {dl}"
+    # m is stored in bf16, so fp32 grads equal to round-off can still land
+    # one bf16 ulp apart; an aux cotangent off by the dp factor reads 5e-3
+    def rel(a, b):
+        a = a.astype(jnp.float32)
+        b = jnp.asarray(jax.device_get(b), jnp.float32)
+        return float(jnp.linalg.norm(b - a) / jnp.linalg.norm(a))
+    worst = max(rel(a, b) for a, b in zip(jax.tree.leaves(s1.m),
+                                          jax.tree.leaves(s2.m)))
+    assert worst < 1e-3, f"first moments diverged {worst}"
+    print("MOE_AUX_DP2_OK", dl, worst)
 """)
 
 ZERO_INVARIANT = textwrap.dedent("""
@@ -105,6 +138,7 @@ ZERO_INVARIANT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.models import build_model
@@ -115,7 +149,7 @@ ZERO_INVARIANT = textwrap.dedent("""
     spec = dataclasses.replace(get_spec("qwen2-1.5b", smoke=True), n_layers=8)
     model = build_model(spec)
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     dp = mesh.shape["data"]
 
     def dev0_bytes(tree):
@@ -181,6 +215,14 @@ def test_pipeline_3d_moe():
     r = _run(MOE_TP)
     assert "olmoe-1b-7b_TP2_OK" in r.stdout \
         and "deepseek-v3_TP2_OK" in r.stdout, \
+        f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
+
+
+def test_moe_aux_whole_microbatch_under_dp():
+    """At pp2·dp2 the MoE aux loss and every gradient match the one-device
+    step in fp32: the load-balance statistics span all data shards."""
+    r = _run(MOE_AUX_DP)
+    assert "MOE_AUX_DP2_OK" in r.stdout, \
         f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
 
 

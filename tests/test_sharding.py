@@ -6,14 +6,14 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_spec
 from repro.core.parallel_config import ZeROStage
 from repro.models import build_model
 from repro.optim.adamw import init_train_state
+from repro.parallel.compat import make_mesh
 from repro.parallel.sharding import (add_dp_axes, grad_shardings,
                                      param_specs, state_shardings)
 
@@ -21,8 +21,7 @@ pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
 def _mesh_1x1():
-    dev = np.array(jax.devices()[:1]).reshape(1, 1)
-    return Mesh(dev, ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
 
 def test_param_specs_follow_paper_rules():
@@ -54,8 +53,8 @@ def test_add_dp_axes_picks_divisible_dim():
     s = add_dp_axes(P(None, "model"), (7, 64), mesh)
     assert s == P(("data",), "model") or s == P("data", "model")
     # indivisible everywhere -> unchanged
-    s2 = add_dp_axes(P(), (3,), Mesh(np.array(jax.devices()[:1]).reshape(1,),
-                                     ("data",)))
+    s2 = add_dp_axes(P(), (3,), make_mesh((1,), ("data",),
+                                          devices=jax.devices()[:1]))
     # 3 % 1 == 0 with a 1-sized axis; use a logical check instead:
     assert s2 in (P(("data",)), P("data"), P())
 

@@ -34,6 +34,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.data.synthetic import config_for, make_batch
@@ -76,7 +77,7 @@ DENSE_FAST = HEADER + textwrap.dedent("""
     batch["mask"] = jnp.broadcast_to(
         (jnp.arange(32) < 28).astype(jnp.float32)[None], (8, 32))
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                     zero=ZeROStage.OS, sp=True)
     s2, m2 = jax.jit(step)(state, batch)
@@ -98,7 +99,7 @@ SP_GATE_AB = HEADER + textwrap.dedent("""
     model = build_model(spec)
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
     batch = make_batch(config_for(spec, 8, 32), 0)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     outs = {}
     for gate in (True, False):
         step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
@@ -127,7 +128,7 @@ DENSE_GRID_BODY = textwrap.dedent("""
     meshes = [(1, 2, 2), (2, 2, 2), (4, 1, 2)] if SCHEDULE == "1f1b" \\
         else [(2, 2, 2), (4, 1, 2)]
     for pp, data, tp in meshes:
-        mesh = jax.make_mesh((pp, data, tp), ("pipe", "data", "model"))
+        mesh = make_mesh((pp, data, tp), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                         schedule=SCHEDULE, n_chunks=N_CHUNKS,
                                         zero=ZeROStage.OS, sp=True)
@@ -159,7 +160,7 @@ MOE_MLA_SP = HEADER + textwrap.dedent("""
         state = init_train_state(model.init(jax.random.PRNGKey(0)))
         batch = make_batch(config_for(spec, 4, 32), 0)
         s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
-        mesh = jax.make_mesh((2, data, 2), ("pipe", "data", "model"))
+        mesh = make_mesh((2, data, 2), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh,
                                         zero=ZeROStage.OS, sp=True)
         s2, m2 = jax.jit(step)(state, batch)
@@ -175,7 +176,7 @@ ZERO_SP_INVARIANT = HEADER + textwrap.dedent("""
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
     batch = make_batch(config_for(spec, 8, 32), 0)
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     dp = mesh.shape["data"]
 
     def dev0_bytes(tree):

@@ -39,7 +39,7 @@ ZB_FAST = HEADER + textwrap.dedent("""
     batch["mask"] = jnp.broadcast_to(
         (jnp.arange(32) < 28).astype(jnp.float32)[None], (8, 32))
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                     schedule="zb1p", zero=ZeROStage.OS)
     s2, m2 = jax.jit(step)(state, batch)
@@ -52,7 +52,7 @@ ZB_GATE_AB = HEADER + textwrap.dedent("""
     model = build_model(spec)
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
     batch = make_batch(config_for(spec, 8, 32), 0)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     outs = {}
     for sched in ("zb1p", "1f1b"):
         for gate in (True, False):
@@ -80,7 +80,7 @@ ZB_DENSE_GRID = HEADER + textwrap.dedent("""
     s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=4)))(state, batch)
     for pp, data, tp, sp in [(2, 2, 2, False), (4, 1, 2, False),
                              (2, 2, 2, True), (4, 1, 2, True)]:
-        mesh = jax.make_mesh((pp, data, tp), ("pipe", "data", "model"))
+        mesh = make_mesh((pp, data, tp), ("pipe", "data", "model"))
         step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                         schedule="zb1p", zero=ZeROStage.OS,
                                         sp=sp)
@@ -102,7 +102,7 @@ ZB_MOE_MLA = HEADER + textwrap.dedent("""
         batch = make_batch(config_for(spec, 4, 32), 0)
         s1, m1 = jax.jit(make_train_step(model, TrainConfig(n_micro=2)))(state, batch)
         for pp, data, tp in [(2, 2, 2), (4, 1, 2)]:
-            mesh = jax.make_mesh((pp, data, tp), ("pipe", "data", "model"))
+            mesh = make_mesh((pp, data, tp), ("pipe", "data", "model"))
             step = make_pipeline_train_step(model, TrainConfig(n_micro=2),
                                             mesh, schedule="zb1p",
                                             zero=ZeROStage.OS)

@@ -21,6 +21,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.data.synthetic import config_for, make_batch
@@ -46,7 +47,7 @@ Z3_EQUIVALENCE = HEADER + textwrap.dedent("""
     batch = make_batch(config_for(spec, 8, 32), 0)
     batch["mask"] = jnp.broadcast_to(
         (jnp.arange(32) < 28).astype(jnp.float32)[None], (8, 32))
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     ref_step = make_pipeline_train_step(model, TrainConfig(n_micro=4), mesh,
                                         zero=ZeROStage.OS_G)
     s1, m1 = jax.jit(ref_step)(state, batch)
@@ -61,6 +62,7 @@ Z3_STATE_INVARIANT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     from repro.configs import get_spec
     from repro.core.parallel_config import ZeROStage
     from repro.models import build_model
@@ -70,7 +72,7 @@ Z3_STATE_INVARIANT = textwrap.dedent("""
     spec = dataclasses.replace(get_spec("qwen2-1.5b", smoke=True), n_layers=8)
     model = build_model(spec)
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     dp = mesh.shape["data"]
 
     def dev0_bytes(tree):
@@ -101,6 +103,7 @@ Z3_CHECKPOINT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, json, tempfile
     import jax, jax.numpy as jnp
+    from repro.parallel.compat import make_mesh
     import numpy as np
     from repro.checkpoint import latest_step, restore, save
     from repro.configs import get_spec
@@ -112,7 +115,7 @@ Z3_CHECKPOINT = textwrap.dedent("""
     spec = dataclasses.replace(get_spec("qwen2-1.5b", smoke=True), n_layers=8)
     model = build_model(spec)
     state = init_train_state(model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "data", "model"))
     sh = state_shardings(state, mesh, ZeROStage.OS_G_PARAMS)
     st = jax.device_put(state, sh)
     with tempfile.TemporaryDirectory() as d:
