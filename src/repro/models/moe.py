@@ -18,6 +18,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes as S
 from repro.core.notation import ModelSpec
 from .layers import Params, dense_init, mlp_apply, mlp_init
 
@@ -31,6 +32,13 @@ class MoEOutput(NamedTuple):
     # executors (SP and/or EP) — consumers wanting global stats must gather
     # over the token-sharding axis.
     router_probs: jnp.ndarray
+    # int32 counts of (token, expert) assignments: ``routed`` of this
+    # rank's routed token set; ``kept`` those that reached an expert (past
+    # the capacity; under EP past the send bucket and the receiving
+    # rank's capacity, counted where the expert runs).  Summed over the
+    # token-sharding and data axes they are the whole microbatch's.
+    routed: Optional[jnp.ndarray] = None
+    kept: Optional[jnp.ndarray] = None
 
 
 def moe_init(key: jax.Array, spec: ModelSpec, dtype=jnp.bfloat16) -> Params:
@@ -177,50 +185,57 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
                                sp_axis=sp_axis, ep=ep, ep_axis=ep_axis,
                                dp_axes=dp_axes, backend=backend)
 
-    probs, gates, eids = _route(p["router"], spec, xt, router_impl)
+    with jax.named_scope(S.MOE_ROUTE):
+        probs, gates, eids = _route(p["router"], spec, xt, router_impl)
 
-    # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(
-        (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
-    stat_axes = tuple(dp_axes) + ((sp_axis,) if sp_axis is not None else ())
-    if stat_axes:
-        from repro.parallel.tp import pmean_sp
-        me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
-    aux = E * jnp.sum(me * ce)
+        # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(
+            (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
+        stat_axes = tuple(dp_axes) + ((sp_axis,) if sp_axis is not None
+                                      else ())
+        if stat_axes:
+            from repro.parallel.tp import pmean_sp
+            me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
+        aux = E * jnp.sum(me * ce)
 
-    C = int(max(1, round(T * K / E * capacity_factor)))
-    flat_eids = eids.reshape(T * K)
-    pos, _ = _positions_in_expert(flat_eids, E)
-    keep = (pos < C)
-    pos_c = jnp.minimum(pos, C - 1)
+        C = int(max(1, round(T * K / E * capacity_factor)))
+        flat_eids = eids.reshape(T * K)
+        pos, _ = _positions_in_expert(flat_eids, E)
+        keep = (pos < C)
+        pos_c = jnp.minimum(pos, C - 1)
 
-    # dispatch: scatter kept tokens into the (E, C, h) buffer (EP-sharded)
-    src = jnp.repeat(xt, K, axis=0) * keep[:, None].astype(x.dtype)
-    buf = jnp.zeros((E, C, h), x.dtype).at[flat_eids, pos_c].add(src)
-    if tp_f is not None:
-        buf = tp_f(buf)
+        # dispatch: scatter kept tokens into the (E, C, h) buffer
+        src = jnp.repeat(xt, K, axis=0) * keep[:, None].astype(x.dtype)
+        buf = jnp.zeros((E, C, h), x.dtype).at[flat_eids, pos_c].add(src)
+        if tp_f is not None:
+            buf = tp_f(buf)
 
     # expert FFN (SwiGLU), batched over the expert dim — the backend's
     # grouped_mlp (pallas: three grouped GEMMs over the flattened
     # static-capacity rows; reference: the einsum triple)
     from .backend import grouped_mlp
-    out_buf = grouped_mlp(buf, p["we_gate"], p["we_up"], p["we_down"],
-                          backend=backend)
-    if tp_g is not None:
-        out_buf = tp_g(out_buf)
+    with jax.named_scope(S.MOE_EXPERTS):
+        out_buf = grouped_mlp(buf, p["we_gate"], p["we_up"], p["we_down"],
+                              backend=backend)
+        if tp_g is not None:
+            out_buf = tp_g(out_buf)
 
     # combine: gather each assignment's expert output, weight, sum over K
-    y_pairs = out_buf[flat_eids, pos_c] * (gates.reshape(T * K)
-                                           * keep.astype(jnp.float32)
-                                           )[:, None].astype(x.dtype)
-    y = y_pairs.reshape(T, K, h).sum(axis=1)
+    with jax.named_scope(S.MOE_ROUTE):
+        y_pairs = out_buf[flat_eids, pos_c] * (gates.reshape(T * K)
+                                               * keep.astype(jnp.float32)
+                                               )[:, None].astype(x.dtype)
+        y = y_pairs.reshape(T, K, h).sum(axis=1)
 
     if e.n_shared:
-        xs = tp_f(xt) if tp_f is not None else xt
-        ys = mlp_apply(p["shared"], spec, xs)
-        y = y + (tp_g(ys) if tp_g is not None else ys)
-    return MoEOutput(y=y.reshape(b, s, h), aux_loss=aux, router_probs=probs)
+        with jax.named_scope(S.MOE_EXPERTS):
+            xs = tp_f(xt) if tp_f is not None else xt
+            ys = mlp_apply(p["shared"], spec, xs)
+            y = y + (tp_g(ys) if tp_g is not None else ys)
+    return MoEOutput(y=y.reshape(b, s, h), aux_loss=aux, router_probs=probs,
+                     routed=jnp.int32(T * K),
+                     kept=jnp.sum(keep, dtype=jnp.int32))
 
 
 def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
@@ -261,35 +276,38 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
         xt = xt_full            # SP residual is already the token shard
     t_loc = xt.shape[0]
 
-    probs, gates, eids = _route(p["router"], spec, xt, router_impl)
-    # per-chunk token sets are disjoint and equal-sized: the pmean of the
-    # per-chunk means is the exact global mean, so aux == the ep=1 value
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(
-        (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
-    stat_axes = tuple(dp_axes) + (ep_axis,)
-    me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
-    aux = E * jnp.sum(me * ce)
+    with jax.named_scope(S.MOE_ROUTE):
+        probs, gates, eids = _route(p["router"], spec, xt, router_impl)
+        # per-chunk token sets are disjoint and equal-sized: the pmean of
+        # the per-chunk means is the exact global mean, so aux == the ep=1
+        # value
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(
+            (jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1)), axis=0) / K
+        stat_axes = tuple(dp_axes) + (ep_axis,)
+        me, ce = pmean_sp(me, stat_axes), pmean_sp(ce, stat_axes)
+        aux = E * jnp.sum(me * ce)
 
-    tk = t_loc * K
-    flat_eids = eids.reshape(tk)
-    flat_gates = gates.reshape(tk)
-    dest = flat_eids // E_loc
-    local_eid = flat_eids % E_loc
+        tk = t_loc * K
+        flat_eids = eids.reshape(tk)
+        flat_gates = gates.reshape(tk)
+        dest = flat_eids // E_loc
+        local_eid = flat_eids % E_loc
 
-    # send: bucket by destination shard, capacity_factor applied once here
-    c_send = int(max(1, round(tk / ep * capacity_factor)))
-    pos_d, _ = _positions_in_expert(dest, ep)
-    keep_s = pos_d < c_send
-    pos_dc = jnp.minimum(pos_d, c_send - 1)
-    src = jnp.repeat(xt, K, axis=0) * keep_s[:, None].astype(x.dtype)
-    send = jnp.zeros((ep, c_send, h), x.dtype).at[dest, pos_dc].add(src)
-    send_eid = _send_eid_buffer(dest, pos_d, local_eid, ep, c_send, E_loc)
+        # send: bucket by destination shard, capacity_factor applied once
+        c_send = int(max(1, round(tk / ep * capacity_factor)))
+        pos_d, _ = _positions_in_expert(dest, ep)
+        keep_s = pos_d < c_send
+        pos_dc = jnp.minimum(pos_d, c_send - 1)
+        src = jnp.repeat(xt, K, axis=0) * keep_s[:, None].astype(x.dtype)
+        send = jnp.zeros((ep, c_send, h), x.dtype).at[dest, pos_dc].add(src)
+        send_eid = _send_eid_buffer(dest, pos_d, local_eid, ep, c_send,
+                                    E_loc)
 
-    recv = jax.lax.all_to_all(send, ep_axis, split_axis=0,
-                              concat_axis=0, tiled=False)
-    recv_eid = jax.lax.all_to_all(send_eid, ep_axis, split_axis=0,
+        recv = jax.lax.all_to_all(send, ep_axis, split_axis=0,
                                   concat_axis=0, tiled=False)
+        recv_eid = jax.lax.all_to_all(send_eid, ep_axis, split_axis=0,
+                                      concat_axis=0, tiled=False)
 
     # Dual-stream shape: the shared expert depends only on the residual,
     # not on the a2a payloads, so it is computed *between* the dispatch
@@ -298,46 +316,55 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     # overlap structure at slot granularity).
     ys = None
     if e.n_shared:
-        xs = tp_f(xt_full) if tp_f is not None else xt_full
-        ys = mlp_apply(p["shared"], spec, xs)
-        if tp_g is not None:
-            ys = tp_g(ys)
+        with jax.named_scope(S.MOE_EXPERTS):
+            xs = tp_f(xt_full) if tp_f is not None else xt_full
+            ys = mlp_apply(p["shared"], spec, xs)
+            if tp_g is not None:
+                ys = tp_g(ys)
 
     # local grouped FFN over the (E/ep, C, h) buffer; C = the global
     # per-expert capacity (tk·ep assignments over E experts), NOT scaled
-    # by capacity_factor a second time
-    rows = recv.reshape(ep * c_send, h)
-    row_eid = recv_eid.reshape(ep * c_send)
-    pos_e, _ = _positions_in_expert(row_eid, E_loc + 1)
-    c_loc = int(max(1, round(tk * ep / E * capacity_factor)))
-    keep_e = (pos_e < c_loc) & (row_eid < E_loc)
-    pos_ec = jnp.minimum(pos_e, c_loc - 1)
-    eid_c = jnp.minimum(row_eid, E_loc - 1)
-    buf = jnp.zeros((E_loc, c_loc, h), x.dtype) \
-        .at[eid_c, pos_ec].add(rows * keep_e[:, None].astype(x.dtype))
+    # by capacity_factor a second time.  Rows the send bucket dropped
+    # arrive as padding (row_eid == E_loc), so keep_e counts exactly the
+    # assignments that reach an expert.
+    with jax.named_scope(S.MOE_ROUTE):
+        rows = recv.reshape(ep * c_send, h)
+        row_eid = recv_eid.reshape(ep * c_send)
+        pos_e, _ = _positions_in_expert(row_eid, E_loc + 1)
+        c_loc = int(max(1, round(tk * ep / E * capacity_factor)))
+        keep_e = (pos_e < c_loc) & (row_eid < E_loc)
+        pos_ec = jnp.minimum(pos_e, c_loc - 1)
+        eid_c = jnp.minimum(row_eid, E_loc - 1)
+        buf = jnp.zeros((E_loc, c_loc, h), x.dtype) \
+            .at[eid_c, pos_ec].add(rows * keep_e[:, None].astype(x.dtype))
 
     # local grouped FFN on the (E/ep, C, h) post-a2a buffer — the EP shard
     # the pallas grouped GEMM sees (expert-dim-sharded weights, full hidden)
     from .backend import grouped_mlp
-    out_buf = grouped_mlp(buf, p["we_gate"], p["we_up"], p["we_down"],
-                          backend=backend)
+    with jax.named_scope(S.MOE_EXPERTS):
+        out_buf = grouped_mlp(buf, p["we_gate"], p["we_up"], p["we_down"],
+                              backend=backend)
 
-    back = (out_buf[eid_c, pos_ec] * keep_e[:, None].astype(x.dtype)) \
-        .reshape(ep, c_send, h)
-    ret = jax.lax.all_to_all(back, ep_axis, split_axis=0,
-                             concat_axis=0, tiled=False)
+    with jax.named_scope(S.MOE_ROUTE):
+        back = (out_buf[eid_c, pos_ec] * keep_e[:, None].astype(x.dtype)) \
+            .reshape(ep, c_send, h)
+        ret = jax.lax.all_to_all(back, ep_axis, split_axis=0,
+                                 concat_axis=0, tiled=False)
 
-    y_pairs = ret[dest, pos_dc] * (flat_gates * keep_s.astype(jnp.float32)
-                                   )[:, None].astype(x.dtype)
-    y = y_pairs.reshape(t_loc, K, h).sum(axis=1)
-    if sp_axis is None:
-        y = unshard_tokens_ep(y, ep_axis, 0)       # rejoin replicated stream
+        y_pairs = ret[dest, pos_dc] * (flat_gates
+                                       * keep_s.astype(jnp.float32)
+                                       )[:, None].astype(x.dtype)
+        y = y_pairs.reshape(t_loc, K, h).sum(axis=1)
+        if sp_axis is None:
+            y = unshard_tokens_ep(y, ep_axis, 0)   # rejoin replicated stream
 
     if ys is not None:
         # shared experts process every token and stay on the ETP path
         y = y + ys
     # probs are the rank's token chunk only (documented: per-shard under EP)
-    return MoEOutput(y=y.reshape(b, s, h), aux_loss=aux, router_probs=probs)
+    return MoEOutput(y=y.reshape(b, s, h), aux_loss=aux, router_probs=probs,
+                     routed=jnp.int32(tk),
+                     kept=jnp.sum(keep_e, dtype=jnp.int32))
 
 
 def moe_forward_dense_ref(p: Params, spec: ModelSpec, x: jnp.ndarray, *,

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes as S
 from repro.core.notation import AttentionKind, FamilyKind, ModelSpec
 from repro.core.params import pp_stage_layers
 from repro.parallel.axes import logical_constraint
@@ -400,10 +401,14 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
                 moe_flag: jnp.ndarray, tp_axis: Optional[str] = None,
                 sp: bool = False, ep: int = 1,
                 dp_axes: Tuple[str, ...] = ()
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One union layer slot.  ``mask`` (scalar f32) turns pad slots into the
-    identity; ``moe_flag`` selects the MoE vs dense-MLP branch when the model
-    mixes kinds (only the selected branch receives gradient).
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One union layer slot: returns (x, aux, counts), ``counts`` the
+    MoE layer's int32 ``[routed, kept]`` assignments (``moe_forward``),
+    zero on dense and pad slots.  ``mask`` (scalar f32) turns pad slots
+    into the identity; ``moe_flag`` selects the MoE vs dense-MLP branch
+    when the model mixes kinds (only the selected branch receives
+    gradient).  The slot's two halves run under the ``attention`` and
+    ``mlp`` scopes (``repro.scopes``).
 
     ``tp_axis`` (the executor's 'model' mesh axis) switches on manual
     Megatron TP: ``spec`` must then be the TP-local view
@@ -449,53 +454,59 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
     is_mla = spec.attention == AttentionKind.MLA
     attn_impl = B.resolve_attn_impl(opts, causal=True,
                                     window=None if is_mla else window)
-    h1 = B.rmsnorm(p["ln1"], x, spec.norm_eps, gemma_style=gemma,
-                   backend=backend)
-    if is_mla:
-        # MLA's replicated down-projections run redundantly on every shard;
-        # the f operator sits on the compressed latents inside _towers.
-        # Under SP the towers consume the *gathered* full-sequence view
-        # (tpf(h1)) — the latents stay full-length on every shard, which is
-        # why the paper's 2bs(d_cq+d_c) terms carry no /sp divisor — and
-        # the latents must NOT carry copy_to_tp: the entry ğ's
-        # reduce-scatter backward already sums the per-shard partial
-        # cotangents, so a psum-bwd on the latents would double-count
-        # (tp× gradients).  The tower weight grads are then head-partial
-        # per shard; the executor's post-loop 'model' psum completes them
-        # (train.pipeline_loop).
-        lat_f = None if (sp or not tp_axis) else tpf
-        mix = M.mla_forward(p["attn"], spec, tpf(h1) if sp else h1,
-                            positions, impl=attn_impl, tpf=lat_f,
-                            backend=backend)
-    else:
-        mix = A.gqa_forward(p["attn"], spec, tpf(h1), positions,
-                            impl=attn_impl, window=window)
-    mix = tpg(mix)
-    x = x + mix * mask.astype(x.dtype)
-    h2 = B.rmsnorm(p["ln2"], x, spec.norm_eps, gemma_style=gemma,
-                   backend=backend)
+    with jax.named_scope(S.ATTENTION):
+        h1 = B.rmsnorm(p["ln1"], x, spec.norm_eps, gemma_style=gemma,
+                       backend=backend)
+        if is_mla:
+            # MLA's replicated down-projections run redundantly on every
+            # shard; the f operator sits on the compressed latents inside
+            # _towers.  Under SP the towers consume the *gathered*
+            # full-sequence view (tpf(h1)) — the latents stay full-length
+            # on every shard, which is why the paper's 2bs(d_cq+d_c) terms
+            # carry no /sp divisor — and the latents must NOT carry
+            # copy_to_tp: the entry ğ's reduce-scatter backward already
+            # sums the per-shard partial cotangents, so a psum-bwd on the
+            # latents would double-count (tp× gradients).  The tower
+            # weight grads are then head-partial per shard; the executor's
+            # post-loop 'model' psum completes them (train.pipeline_loop).
+            lat_f = None if (sp or not tp_axis) else tpf
+            mix = M.mla_forward(p["attn"], spec, tpf(h1) if sp else h1,
+                                positions, impl=attn_impl, tpf=lat_f,
+                                backend=backend)
+        else:
+            mix = A.gqa_forward(p["attn"], spec, tpf(h1), positions,
+                                impl=attn_impl, window=window)
+        mix = tpg(mix)
+        x = x + mix * mask.astype(x.dtype)
     aux = jnp.zeros((), jnp.float32)
-    has_mlp, has_moe = "mlp" in p, "moe" in p
-    if has_moe:
-        out = moe_forward(p["moe"], spec, h2,
-                          capacity_factor=opts.capacity_factor,
-                          router_impl=opts.router_impl,
-                          tp_f=tpf if tp_axis else None,
-                          tp_g=tpg if tp_axis else None,
-                          sp_axis=tp_axis if sp else None,
-                          ep=ep, ep_axis=tp_axis if ep > 1 else None,
-                          dp_axes=dp_axes, backend=backend)
-        sel = moe_flag.astype(x.dtype)
-        delta = out.y * sel
-        if has_mlp:
-            delta = delta + tpg(mlp_apply(p["mlp"], spec, tpf(h2))) * (1 - sel)
-        aux = out.aux_loss * moe_flag * mask
-    elif has_mlp:
-        delta = tpg(mlp_apply(p["mlp"], spec, tpf(h2)))
-    else:
-        delta = jnp.zeros_like(x)
-    x = x + delta * mask.astype(x.dtype)
-    return x, aux
+    counts = jnp.zeros((2,), jnp.int32)
+    with jax.named_scope(S.MLP):
+        h2 = B.rmsnorm(p["ln2"], x, spec.norm_eps, gemma_style=gemma,
+                       backend=backend)
+        has_mlp, has_moe = "mlp" in p, "moe" in p
+        if has_moe:
+            out = moe_forward(p["moe"], spec, h2,
+                              capacity_factor=opts.capacity_factor,
+                              router_impl=opts.router_impl,
+                              tp_f=tpf if tp_axis else None,
+                              tp_g=tpg if tp_axis else None,
+                              sp_axis=tp_axis if sp else None,
+                              ep=ep, ep_axis=tp_axis if ep > 1 else None,
+                              dp_axes=dp_axes, backend=backend)
+            sel = moe_flag.astype(x.dtype)
+            delta = out.y * sel
+            if has_mlp:
+                delta = delta + tpg(mlp_apply(p["mlp"], spec,
+                                              tpf(h2))) * (1 - sel)
+            aux = out.aux_loss * moe_flag * mask
+            counts = jnp.stack([out.routed, out.kept]) * (
+                moe_flag * mask > 0.5).astype(jnp.int32)
+        elif has_mlp:
+            delta = tpg(mlp_apply(p["mlp"], spec, tpf(h2)))
+        else:
+            delta = jnp.zeros_like(x)
+        x = x + delta * mask.astype(x.dtype)
+    return x, aux, counts
 
 
 def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
@@ -506,14 +517,15 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
                          sp: bool = False, ep: int = 1,
                          remat: bool = True,
                          dp_axes: Tuple[str, ...] = ()
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scan this stage's l_max union slots.  ``layers_p`` leaves are
-    (l_max, ...); ``mask``/``moe_flag`` are (l_max,).  With ``tp_axis`` the
-    slots run manual TP; with ``sp`` additionally Megatron sequence
-    parallelism — ``x`` is then the seq-sharded residual; with ``ep`` the
-    MoE slots dispatch expert-parallel over the same axis; ``dp_axes``
-    combines the MoE load-balance statistics across the data shards (see
-    ``_slot_apply``).
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Scan this stage's l_max union slots; returns (x, aux, counts),
+    ``counts`` the MoE assignments ``[routed, kept]`` summed over them.
+    ``layers_p`` leaves are (l_max, ...); ``mask``/``moe_flag`` are
+    (l_max,).  With ``tp_axis`` the slots run manual TP; with ``sp``
+    additionally Megatron sequence parallelism — ``x`` is then the
+    seq-sharded residual; with ``ep`` the MoE slots dispatch
+    expert-parallel over the same axis; ``dp_axes`` combines the MoE
+    load-balance statistics across the data shards (see ``_slot_apply``).
 
     ``remat=False`` bypasses ``opts.recompute`` for this call: a vjp through
     the stage then stores the slot internals instead of recomputing them —
@@ -523,14 +535,16 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
     compute zero-bubble trades stash memory for)."""
 
     def body(carry, inp):
-        xc, aux = carry
+        xc, aux, cnt = carry
         p_slot, m, f = inp
-        xc, a = _slot_apply(p_slot, spec, opts, xc, positions, m, f, tp_axis,
-                            sp, ep, dp_axes)
-        return (xc, aux + a), None
+        xc, a, c = _slot_apply(p_slot, spec, opts, xc, positions, m, f,
+                               tp_axis, sp, ep, dp_axes)
+        return (xc, aux + a, cnt + c), None
 
     if remat:
         body = _remat(body, opts.recompute)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (layers_p, mask, moe_flag))
-    return x, aux
+    init = (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32))
+    with jax.named_scope(S.LAYER_SCAN):
+        (x, aux, counts), _ = jax.lax.scan(body, init,
+                                           (layers_p, mask, moe_flag))
+    return x, aux, counts

@@ -185,6 +185,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import scopes
 from repro.core.notation import AttentionKind
 from repro.core.parallel_config import ZeROStage
 from repro.models import backend as B
@@ -398,32 +399,36 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             final-norm output before the column-sharded projection.
             ``remat=False`` (zb1p's split backward) bypasses the slot
             checkpointing so each half of the B/W split replays the chunk
-            exactly once."""
-            if tp_axis:
-                x0 = embed_tp(ps["embed"]["w"], tok, axis=tp_axis,
-                              scale_by_dim=gemma, h=spec.h, sp=sp)
-            else:
-                x0 = embed_apply(ps["embed"], tok, scale_by_dim=gemma,
-                                 h=spec.h)
-            x = jnp.where(first_l[c] > 0.5, x0, x_recv)
+            exactly once.  Also returns the slots' MoE ``[routed, kept]``
+            counts (``pipeline_stage_apply``)."""
+            with jax.named_scope(scopes.EMBED):
+                if tp_axis:
+                    x0 = embed_tp(ps["embed"]["w"], tok, axis=tp_axis,
+                                  scale_by_dim=gemma, h=spec.h, sp=sp)
+                else:
+                    x0 = embed_apply(ps["embed"], tok, scale_by_dim=gemma,
+                                     h=spec.h)
+                x = jnp.where(first_l[c] > 0.5, x0, x_recv)
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b_loc, s))
-            y, aux = pipeline_stage_apply(pl, spec_run, opts, x, positions,
-                                          smask[c], sflag[c], tp_axis,
-                                          sp=sp, ep=ep, remat=remat,
-                                          dp_axes=data_axes)
-            z = B.rmsnorm(ps["final_norm"], y, spec.norm_eps,
-                          gemma_style=gemma, backend=B.resolve_backend(opts))
-            w_out = ps["embed"]["w"].T if spec.tie_embeddings \
-                else ps["head"]["w"]
-            if tp_axis:
-                zin = gather_from_sp(z, tp_axis, 1) if sp \
-                    else copy_to_tp(z, tp_axis)
-                logits = zin @ w_out
-                ce = ce_sum_tp(logits, tok, _ce_mask(mm, tok), axis=tp_axis)
-            else:
-                logits = z @ w_out
-                ce = _ce_sum(logits, tok, mm)
-            return y, ce, aux
+            y, aux, counts = pipeline_stage_apply(
+                pl, spec_run, opts, x, positions, smask[c], sflag[c],
+                tp_axis, sp=sp, ep=ep, remat=remat, dp_axes=data_axes)
+            with jax.named_scope(scopes.HEAD):
+                z = B.rmsnorm(ps["final_norm"], y, spec.norm_eps,
+                              gemma_style=gemma,
+                              backend=B.resolve_backend(opts))
+                w_out = ps["embed"]["w"].T if spec.tie_embeddings \
+                    else ps["head"]["w"]
+                if tp_axis:
+                    zin = gather_from_sp(z, tp_axis, 1) if sp \
+                        else copy_to_tp(z, tp_axis)
+                    logits = zin @ w_out
+                    ce = ce_sum_tp(logits, tok, _ce_mask(mm, tok),
+                                   axis=tp_axis)
+                else:
+                    logits = z @ w_out
+                    ce = _ce_sum(logits, tok, mm)
+            return y, ce, aux, counts
 
         def micro_at(arr, m):
             return jax.lax.dynamic_index_in_dim(arr, m, 0, keepdims=False)
@@ -463,9 +468,9 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
 
         def tick(carry, t):
             if zb:
-                xbuf, gbuf, gl, gsh, loss, aux_acc, dx_c, stash = carry
+                xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx_c, stash = carry
             else:
-                xbuf, gbuf, gl, gsh, loss, aux_acc, dx_c = carry
+                xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx_c = carry
             ring_dn = [(i, (i + 1) % S) for i in range(S)]
             ring_up = [(i, (i - 1) % S) for i in range(S)]
 
@@ -489,21 +494,24 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             fm = tabs["f_micro"][t, d]
             fc = tabs["f_chunk"][t, d]
 
+            @jax.named_scope(scopes.TICK_F)
             def f_on():
                 x_in = _dyn(xbuf, tabs["f_xidx"][t, d])
                 tok_f = micro_at(toks, fm)
                 mm_f = None if mmask is None else micro_at(mmask, fm)
-                y_, ce_sum, aux_f = chunk_fn(gather_l(layers_at(fc)),
-                                             gather_s(p_shared), x_in,
-                                             tok_f, mm_f, fc)
+                y_, ce_sum, aux_f, cnt_f = chunk_fn(
+                    gather_l(layers_at(fc)), gather_s(p_shared), x_in,
+                    tok_f, mm_f, fc)
                 ce_m = _psum(ce_sum, data_axes) / jnp.maximum(
                     count_g(tok_f, mm_f), 1.0)
-                return y_, loss + last_l[fc] * ce_m, aux_acc + aux_f
+                return (y_, loss + last_l[fc] * ce_m, aux_acc + aux_f,
+                        cnt + cnt_f)
 
             def f_off():
-                return jnp.zeros((b_loc, s_loc, h), adt), loss, aux_acc
+                return jnp.zeros((b_loc, s_loc, h), adt), loss, aux_acc, cnt
 
-            y, loss, aux_acc = _cond(tabs["f_act"][t, d] > 0.5, f_on, f_off)
+            y, loss, aux_acc, cnt = _cond(tabs["f_act"][t, d] > 0.5, f_on,
+                                          f_off)
 
             # -- issue: this tick's forward-boundary permutes (consumed
             #    after the backward below — the transfer overlaps B/W) ----
@@ -532,6 +540,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 # zb_pending_peak).  dx and the shared embed/head/norm
                 # grads retire here; the per-layer dW parks in its stash
                 # slot until the schedule's dedicated W tick below.
+                @jax.named_scope(scopes.TICK_B)
                 def b_on():
                     tok_b = micro_at(toks, bm)
                     mm_b = None if mmask is None else micro_at(mmask, bm)
@@ -542,16 +551,20 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                         lambda pl_, ps_, x_: chunk_fn(gather_l(pl_),
                                                       gather_s(ps_), x_,
                                                       tok_b, mm_b, bc,
-                                                      remat=False),
+                                                      remat=False)[:3],
                         pl_b, p_shared, x_sv)
                     dpl, dps, dx_ = vjp_fn(_cotangents(tok_b, mm_b, bc, dy))
-                    pend = jax.tree.map(
-                        lambda g_: g_.astype(jnp.float32), dpl)
-                    stash_ = jax.tree.map(
-                        lambda st, g_: jax.lax.dynamic_update_index_in_dim(
-                            st, g_, tabs["b_sidx"][t, d], 0), stash, pend)
-                    gsh_ = jax.tree.map(
-                        lambda a, g_: a + g_.astype(jnp.float32), gsh, dps)
+                    with jax.named_scope(scopes.GRAD_ACCUM):
+                        pend = jax.tree.map(
+                            lambda g_: g_.astype(jnp.float32), dpl)
+                        stash_ = jax.tree.map(
+                            lambda st, g_:
+                            jax.lax.dynamic_update_index_in_dim(
+                                st, g_, tabs["b_sidx"][t, d], 0),
+                            stash, pend)
+                        gsh_ = jax.tree.map(
+                            lambda a, g_: a + g_.astype(jnp.float32), gsh,
+                            dps)
                     return stash_, gsh_, dx_
 
                 def b_off():
@@ -567,6 +580,8 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 #    fused path, just later) ------------------------------
                 wc = tabs["w_chunk"][t, d]
 
+                @jax.named_scope(scopes.TICK_W)
+                @jax.named_scope(scopes.GRAD_ACCUM)
                 def w_on():
                     pend = jax.tree.map(
                         lambda st: _dyn(st, tabs["w_sidx"][t, d]), stash)
@@ -581,6 +596,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
 
                 gl = _cond(tabs["w_act"][t, d] > 0.5, w_on, w_off)
             else:
+                @jax.named_scope(scopes.TICK_B)
                 def b_on():
                     tok_b = micro_at(toks, bm)
                     mm_b = None if mmask is None else micro_at(mmask, bm)
@@ -590,17 +606,20 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                     _, vjp_fn = jax.vjp(
                         lambda pl_, ps_, x_: chunk_fn(gather_l(pl_),
                                                       gather_s(ps_), x_,
-                                                      tok_b, mm_b, bc),
+                                                      tok_b, mm_b, bc)[:3],
                         pl_b, p_shared, x_sv)
                     dpl, dps, dx_ = vjp_fn(_cotangents(tok_b, mm_b, bc, dy))
-                    cur = jax.tree.map(lambda a: _dyn(a, bc), gl)
-                    upd = jax.tree.map(
-                        lambda a, g_: a + g_.astype(jnp.float32), cur, dpl)
-                    gl_ = jax.tree.map(
-                        lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                            a, u, bc, 0), gl, upd)
-                    gsh_ = jax.tree.map(
-                        lambda a, g_: a + g_.astype(jnp.float32), gsh, dps)
+                    with jax.named_scope(scopes.GRAD_ACCUM):
+                        cur = jax.tree.map(lambda a: _dyn(a, bc), gl)
+                        upd = jax.tree.map(
+                            lambda a, g_: a + g_.astype(jnp.float32), cur,
+                            dpl)
+                        gl_ = jax.tree.map(
+                            lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                                a, u, bc, 0), gl, upd)
+                        gsh_ = jax.tree.map(
+                            lambda a, g_: a + g_.astype(jnp.float32), gsh,
+                            dps)
                     return gl_, gsh_, dx_
 
                 def b_off():
@@ -614,74 +633,85 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 xbuf = write(xbuf, tabs["rfd_act"], tabs["rfd_idx"], y_dn)
             if use_f_up:
                 xbuf = write(xbuf, tabs["rfu_act"], tabs["rfu_idx"], y_up)
-            out = (xbuf, gbuf, gl, gsh, loss, aux_acc, dx)
+            out = (xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx)
             return (out + (stash,) if zb else out), None
 
-        zeros_like_f32 = lambda tree: jax.tree.map(
-            lambda a: jnp.zeros(a.shape, jnp.float32), tree)
+        @jax.named_scope(scopes.GRAD_ACCUM)
+        def zeros_like_f32(tree):
+            return jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                                tree)
+
         init = (jnp.zeros((V * XS, b_loc, s_loc, h), adt),
                 jnp.zeros((V * GS, b_loc, s_loc, h), adt),
                 zeros_like_f32(p_layers),
                 zeros_like_f32(p_shared),
                 jnp.zeros((), jnp.float32),
                 jnp.zeros((), jnp.float32),
+                jnp.zeros((2,), jnp.int32),          # MoE [routed, kept]
                 jnp.zeros((b_loc, s_loc, h), adt))    # in-flight dx carry
         if zb:
             # fp32 pending-dW stash: one chunk-shaped grad pytree per
             # stash slot, written at B, flushed at the dedicated W tick
-            init = init + (jax.tree.map(
-                lambda a: jnp.zeros((V * SS,) + a.shape[1:], jnp.float32),
-                p_layers),)
+            with jax.named_scope(scopes.GRAD_ACCUM):
+                init = init + (jax.tree.map(
+                    lambda a: jnp.zeros((V * SS,) + a.shape[1:],
+                                        jnp.float32), p_layers),)
         fin, _ = jax.lax.scan(tick, init, jnp.arange(T))
-        _, _, gl, gsh, loss, aux_acc = fin[:6]
+        _, _, gl, gsh, loss, aux_acc, cnt = fin[:7]
 
-        g = dict(gsh, layers=gl)
-        if sp or ep > 1:
-            # Token-sharded grad completion: weights applied *inside* a
-            # token-sharded region accumulate grads from their shard's
-            # tokens only; one psum over 'model' assembles the full
-            # gradient for exactly those leaves.  Under SP that is the
-            # norm scales, the MoE router and MLA's replicated latent
-            # towers (which run without copy_to_tp under SP — the entry
-            # ğ's reduce-scatter backward does the cross-shard sum — so
-            # their grads are head-partial).  Under EP (with or without
-            # SP) the router is consumed on each rank's disjoint token
-            # chunk, so it needs the same completion; the expert weights
-            # themselves do NOT — the a2a already delivered every rank
-            # the full token set bound for its experts, so their local
-            # grads are exact.  Every other leaf stays exact-local (the
-            # boundary operators carry the cross-shard sums in their
-            # backward rules) and must NOT be psummed — that would scale
-            # it by tp.
-            lay = dict(g["layers"])
-            if sp:
-                for k in ("ln1", "ln2"):
-                    lay[k] = jax.lax.psum(lay[k], tp_axis)
-            if "moe" in lay:
-                lay["moe"] = dict(
-                    lay["moe"],
-                    router=jax.lax.psum(lay["moe"]["router"], tp_axis))
-            if sp and spec.attention == AttentionKind.MLA:
-                attn_g = dict(lay["attn"])
-                for k in ("w_dq", "w_dkv", "w_kr", "q_norm", "kv_norm"):
-                    attn_g[k] = jax.lax.psum(attn_g[k], tp_axis)
-                lay["attn"] = attn_g
-            g = dict(g, layers=lay)
-            if sp:
-                g = dict(g, final_norm=jax.lax.psum(g["final_norm"],
-                                                    tp_axis))
-        if gdims_g is not None:
-            # ZeRO-3: gathered leaves' grads were already cross-DP-summed
-            # (and re-sharded) by gather_params' backward psum_scatter —
-            # a data psum here would double-count them.  Replicate-fallback
-            # leaves (dm < 0) still need the sum.
-            g = jax.tree.map(
-                lambda a, dm: (_psum(a, data_axes) if dm < 0 else a)[None],
-                g, gdims_g)
-        else:
-            g = jax.tree.map(lambda a: _psum(a, data_axes)[None], g)
+        with jax.named_scope(scopes.GRAD_SYNC):
+            g = dict(gsh, layers=gl)
+            if sp or ep > 1:
+                # Token-sharded grad completion: weights applied *inside* a
+                # token-sharded region accumulate grads from their shard's
+                # tokens only; one psum over 'model' assembles the full
+                # gradient for exactly those leaves.  Under SP that is the
+                # norm scales, the MoE router and MLA's replicated latent
+                # towers (which run without copy_to_tp under SP — the entry
+                # ğ's reduce-scatter backward does the cross-shard sum — so
+                # their grads are head-partial).  Under EP (with or without
+                # SP) the router is consumed on each rank's disjoint token
+                # chunk, so it needs the same completion; the expert weights
+                # themselves do NOT — the a2a already delivered every rank
+                # the full token set bound for its experts, so their local
+                # grads are exact.  Every other leaf stays exact-local (the
+                # boundary operators carry the cross-shard sums in their
+                # backward rules) and must NOT be psummed — that would scale
+                # it by tp.
+                lay = dict(g["layers"])
+                if sp:
+                    for k in ("ln1", "ln2"):
+                        lay[k] = jax.lax.psum(lay[k], tp_axis)
+                if "moe" in lay:
+                    lay["moe"] = dict(
+                        lay["moe"],
+                        router=jax.lax.psum(lay["moe"]["router"], tp_axis))
+                if sp and spec.attention == AttentionKind.MLA:
+                    attn_g = dict(lay["attn"])
+                    for k in ("w_dq", "w_dkv", "w_kr", "q_norm", "kv_norm"):
+                        attn_g[k] = jax.lax.psum(attn_g[k], tp_axis)
+                    lay["attn"] = attn_g
+                g = dict(g, layers=lay)
+                if sp:
+                    g = dict(g, final_norm=jax.lax.psum(g["final_norm"],
+                                                        tp_axis))
+            if gdims_g is not None:
+                # ZeRO-3: gathered leaves' grads were already cross-DP-summed
+                # (and re-sharded) by gather_params' backward psum_scatter —
+                # a data psum here would double-count them.  Replicate-fallback
+                # leaves (dm < 0) still need the sum.
+                g = jax.tree.map(
+                    lambda a, dm: (_psum(a, data_axes) if dm < 0 else a)[None],
+                    g, gdims_g)
+            else:
+                g = jax.tree.map(lambda a: _psum(a, data_axes)[None], g)
         loss_sum = jax.lax.psum(loss + 0.01 * aux_acc, "pipe")
-        return g, loss_sum
+        # the MoE counts of every F tick: each pipe rank holds other
+        # layers, each data shard other samples and, where the tokens are
+        # sharded over 'model' (SP, EP), each model shard other tokens
+        cnt_axes = ("pipe",) + data_axes + (
+            (tp_axis,) if tp_axis and (sp or ep > 1) else ())
+        return g, loss_sum, jax.lax.psum(cnt, cnt_axes)
 
     data_size = 1
     for a in data_axes:
@@ -719,9 +749,11 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 spec, tp, ep,
                 tokens_per_rank=(toks.shape[1] // data_size) * toks.shape[2])
         if zero != ZeROStage.NONE:
-            state = _zero_constrain(state)
-        stacked = stack_pipeline_params(state.params, spec, S,
-                                        schedule=schedule, n_chunks=V)
+            with jax.named_scope(scopes.OPTIMIZER):
+                state = _zero_constrain(state)
+        with jax.named_scope(scopes.STAGE_STACK):
+            stacked = stack_pipeline_params(state.params, spec, S,
+                                            schedule=schedule, n_chunks=V)
         if zp and data_axes:
             stage_specs, gdims = zero3_stage_specs(stacked, mesh,
                                                    rules=rules)
@@ -740,25 +772,32 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             return _run(stacked_l, masks_l, flags_l, firsts_l, lasts_l,
                         toks_l, rest[0] if rest else None, gdims=gdims)
 
-        g_st, loss_sum = shard_map(
+        g_st, loss_sum, counts = shard_map(
             inner, mesh=mesh,
             in_specs=(stage_specs, P("pipe", None, None), P("pipe", None, None),
                       P("pipe", None), P("pipe", None)) + mspecs,
-            out_specs=(stage_specs, P()),
+            out_specs=(stage_specs, P(), P()),
         )(stacked, masks_all, flags_all, first_all, last_all, *margs)
-        grads = unstack_pipeline_grads(g_st, state.params, spec, S,
-                                       schedule=schedule, n_chunks=V)
-        grads = jax.tree.map(lambda a: a / M, grads)
+        with jax.named_scope(scopes.STAGE_STACK):
+            grads = unstack_pipeline_grads(g_st, state.params, spec, S,
+                                           schedule=schedule, n_chunks=V)
         if zero in (ZeROStage.OS_G, ZeROStage.OS_G_PARAMS):
             # ZeRO-2: reduce-scatter the fp32 accumulation buffers onto the
             # per-stage DP group before the (sharded) optimizer update
-            grads = jax.lax.with_sharding_constraint(
-                grads, grad_shardings(state.params, mesh, zero,
-                                      rules=rules))
-        new_state, opt_metrics = adamw_update(state, grads, cfg.adamw)
-        if zero != ZeROStage.NONE:
-            new_state = _zero_constrain(new_state)
-        metrics = {"loss": loss_sum / M, **opt_metrics}
+            with jax.named_scope(scopes.GRAD_SYNC):
+                grads = jax.lax.with_sharding_constraint(
+                    grads, grad_shardings(state.params, mesh, zero,
+                                          rules=rules))
+        with jax.named_scope(scopes.OPTIMIZER):
+            grads = jax.tree.map(lambda a: a / M, grads)
+            new_state, opt_metrics = adamw_update(state, grads, cfg.adamw)
+            if zero != ZeROStage.NONE:
+                new_state = _zero_constrain(new_state)
+        # whole-step MoE assignments (int32; 0 for a dense model): routed,
+        # and kept by the capacity (under EP: the send bucket and the
+        # receiving rank's capacity), counted in the forward ticks only
+        metrics = {"loss": loss_sum / M, **opt_metrics,
+                   "moe_routed": counts[0], "moe_kept": counts[1]}
         return new_state, metrics
 
     return step

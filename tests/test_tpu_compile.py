@@ -93,3 +93,51 @@ def test_grouped_mlp_compiles_for_v5e(one_chip, monkeypatch, E, C, h, f):
                                              backend="pallas"),
         buf, wi, wi, wo)
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_executor_scopes_survive_tpu_fusion(topo, monkeypatch):
+    """A tiny MoE step of the pipeline executor, compiled for one v5e
+    chip: the layer scopes reach the ``op_name`` metadata of the fusions
+    and kernel calls the chip runs (what a profile is read by), and the
+    kernels keep the jitted wrappers' names that the benchmark finds them
+    by."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import scopes as S
+    from repro.configs import get_spec
+    from repro.core.parallel_config import ZeROStage
+    from repro.models import build_model
+    from repro.models.transformer import ModelOptions
+    from repro.optim.adamw import init_train_state
+    from repro.parallel.compat import make_mesh
+    from repro.parallel.sharding import state_shardings
+    from repro.train import pipeline_loop as PL
+    from repro.train.loop import TrainConfig
+
+    monkeypatch.setattr(K, "default_interpret", lambda: False)
+    model = build_model(get_spec("olmoe-1b-7b", smoke=True),
+                        ModelOptions(backend="pallas"))
+    mesh = make_mesh((1, 1, 1), ("pipe", "data", "model"),
+                     devices=topo.devices[:1])
+    step = PL.make_pipeline_train_step(model, TrainConfig(n_micro=2), mesh)
+    abstract = jax.eval_shape(lambda k: init_train_state(model.init(k)),
+                              jax.random.PRNGKey(0))
+    shardings = state_shardings(abstract, mesh, ZeROStage.NONE,
+                                rules=PL._EXEC_TP_RULES)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (2, 256), jnp.int32, sharding=NamedSharding(mesh, P()))}
+    text = _compiled_text(step, state, batch)
+    smap = S.scope_map(text)
+    launched = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \S.*?\s(?:fusion|custom-call)\(",
+        text, re.M)
+    layers = {S.layer_of(smap[n]) for n in launched}
+    assert {S.ATTENTION, S.MOE_EXPERTS, S.HEAD, S.OPTIMIZER} <= layers
+    kernels = {re.sub(r"\.\d+$", "", n) for n in launched
+               if n.startswith("_")}
+    assert {"_flash_attention_jit", "_gmm_jit"} <= kernels
