@@ -10,7 +10,9 @@ per layer and per phase:
 * ``tick.F`` / ``tick.B`` / ``tick.W``: the schedule's forward, backward
   and (zb1p) weight-gradient ticks.  Under ``tick.B`` the chunk's forward
   runs again inside ``jax.vjp`` — its ops carry ``jvp(<scope>)`` — before
-  the backward, whose ops carry ``transpose(jvp(<scope>))``;
+  the backward, whose ops carry ``transpose(jvp(<scope>))``.  The last
+  model chunk has no ``tick.F``: its forward runs only there (at pp = 1,
+  every chunk's);
 * the layers below, one per op; the innermost wins where they nest: a
   slot's ``attention`` and ``mlp`` sit inside ``layer_scan`` (the scan
   over a chunk's layer slots, which slices each slot's weights and stacks

@@ -75,7 +75,13 @@ rings whose statically-coloured size is the executor's true in-flight bound
 Backward is *manual* (the tick loop is not differentiated): each rank keeps
 its in-flight boundary inputs, recomputes the retiring chunk's forward, and
 pulls gradients through ``jax.vjp`` with the downstream cotangent —
-chunk-granular recompute, the standard JAX pipeline construction.  Under
+chunk-granular recompute, the standard JAX pipeline construction.  The
+last model chunk's forward runs once, inside its backward tick: its output
+has no consumer, so its F tick is dropped (``train.schedules.forward_runs``
+checks that no boundary send reads it) and the vjp's primal pass supplies
+the microbatch's loss, aux and MoE counts.  At pp = 1 that is every
+forward, so the step emits no F branch at all; the step's ``fwd_fused``
+metric counts the forwards served this way.  Under
 ``dualpipe`` every model chunk lives on two ranks (the schedule's 2×
 parameter cost); ``unstack_pipeline_grads`` sums both copies' gradients.
 
@@ -204,7 +210,8 @@ from repro.parallel.tp import (ce_sum_tp, check_ep_supported,
                                copy_to_tp, embed_tp, gather_from_sp,
                                gather_params, tp_local_spec)
 from repro.train.loop import TrainConfig, _split_micro
-from repro.train.schedules import build_exec_tables, make_schedule
+from repro.train.schedules import (build_exec_tables, forward_runs,
+                                   make_schedule)
 
 PyTree = Any
 
@@ -231,11 +238,17 @@ def _ce_mask(mask: Optional[jnp.ndarray], tokens: jnp.ndarray) -> jnp.ndarray:
 def _ce_sum(logits: jnp.ndarray, tokens: jnp.ndarray,
             mask: Optional[jnp.ndarray]) -> jnp.ndarray:
     """Unnormalized token-CE sum over the local batch shard (fp32), the
-    summand of Model.loss's masked mean."""
+    summand of Model.loss's masked mean.  The gold logit is selected by a
+    compare against the vocabulary index rather than gathered: the select
+    fuses into the log-sum-exp's pass over the logits, and its backward
+    into the softmax's, where a gather and its scatter keep whole fp32
+    copies of the logits live in the backward tick, whose vjp returns
+    this sum as the loss."""
     targets = tokens[:, 1:]
     lg = logits[:, :-1].astype(jnp.float32)
     logz = jax.scipy.special.logsumexp(lg, axis=-1)
-    gold = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0]
+    gold = jnp.sum(jnp.where(jnp.arange(lg.shape[-1]) == targets[..., None],
+                             lg, 0.0), axis=-1)
     return jnp.sum((logz - gold) * _ce_mask(mask, tokens))
 
 
@@ -327,6 +340,13 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
         "rfd_act", "rfd_idx", "rfu_act", "rfu_idx")
         + (("w_act", "w_micro", "w_chunk", "b_sidx", "w_sidx")
            if zb else ())}
+    # The last model chunk's forward runs once, inside its backward tick's
+    # vjp, which also yields its loss, aux and MoE counts: its F tick is
+    # dropped (``forward_runs`` checks that no boundary send reads it).
+    # Where no forward is left, as at pp = 1, no F branch is emitted.
+    f_run = forward_runs(tab, part.last_flag)
+    run_f = bool(f_run.any())
+    tabs["f_run"] = jnp.asarray(f_run)
     # Grad arrivals are consumed one tick late: the input-gradient computed
     # at tick t rides the scan carry, its ppermute is issued at the TOP of
     # tick t+1 (so the ring transfer overlaps t+1's forward compute) and the
@@ -453,24 +473,45 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             return jax.tree.map(
                 lambda a_, b_: jnp.where(pred, a_, b_), on_v, off_v)
 
-        def _cotangents(tok, mm, c, dy):
-            """Output cotangents of ``chunk_fn`` for retiring chunk ``c``:
-            the boundary grad ``dy`` (zeroed on the last model chunk, whose
-            ``y`` has no consumer), the CE mean cotangent (nonzero only on
-            the last chunk) and the 0.01 aux weight (aux is the
-            whole-microbatch value on every data shard; its backward hands
-            each shard 1/data_size of the load-balance cotangent, and the
-            grads are psummed over the data axes below)."""
+        def chunk_vjp(m, c, x_sv, dy, remat=True):
+            """Gradients of microbatch ``m`` through chunk ``c``, and what
+            the chunk's forward adds to the step's metrics.  The vjp's
+            primal pass is the chunk's forward; on the last model chunk it
+            is the only one (no F tick runs it), so that chunk's mean CE,
+            aux and MoE counts, and one fused forward, are taken from it.
+            On other chunks they are zero: their F ticks took them."""
+            tok = micro_at(toks, m)
+            mm = None if mmask is None else micro_at(mmask, m)
+
+            def f(pl_, ps_, x_):
+                y_, ce_, aux_, cnt_ = chunk_fn(gather_l(pl_), gather_s(ps_),
+                                               x_, tok, mm, c, remat=remat)
+                return (y_, ce_, aux_), cnt_
+
+            (_, ce, aux), vjp_fn, cnt_c = jax.vjp(
+                f, layers_at(c), p_shared, x_sv, has_aux=True)
+            n_tok = jnp.maximum(count_g(tok, mm), 1.0)
             lastc = last_l[c]
-            dy_cot = jnp.where(lastc < 0.5, dy, jnp.zeros((), dy.dtype))
-            dce = lastc / jnp.maximum(count_g(tok, mm), 1.0)
-            return dy_cot, dce, jnp.float32(0.01)
+            last = lastc > 0.5
+            # output cotangents: the boundary grad ``dy`` (zeroed on the
+            # last model chunk, whose ``y`` has no consumer), the CE mean
+            # over the microbatch's target tokens (nonzero only on the
+            # last chunk) and the 0.01 aux weight (aux is the
+            # whole-microbatch value on every data shard; its backward
+            # hands each shard 1/data_size of the load-balance cotangent,
+            # and the grads are psummed over the data axes below)
+            grads = vjp_fn((jnp.where(last, jnp.zeros((), dy.dtype), dy),
+                            lastc / n_tok, jnp.float32(0.01)))
+            return grads, (lastc * _psum(ce, data_axes) / n_tok,
+                           lastc * aux, jnp.where(last, cnt_c, 0),
+                           last.astype(jnp.int32))
 
         def tick(carry, t):
             if zb:
-                xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx_c, stash = carry
+                (xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, nf, dx_c,
+                 stash) = carry
             else:
-                xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx_c = carry
+                xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, nf, dx_c = carry
             ring_dn = [(i, (i + 1) % S) for i in range(S)]
             ring_up = [(i, (i - 1) % S) for i in range(S)]
 
@@ -490,28 +531,28 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             if use_b_up:
                 dx_up = jax.lax.ppermute(dx_c, "pipe", ring_up)
 
-            # -- forward (cond-gated): the schedule's (micro, chunk) ------
-            fm = tabs["f_micro"][t, d]
-            fc = tabs["f_chunk"][t, d]
+            # -- forward (cond-gated): the schedule's (micro, chunk), not
+            #    the last model chunk, whose forward runs in its B tick;
+            #    no loss: only the last chunk's CE counts --------------
+            if run_f:
+                fm = tabs["f_micro"][t, d]
+                fc = tabs["f_chunk"][t, d]
 
-            @jax.named_scope(scopes.TICK_F)
-            def f_on():
-                x_in = _dyn(xbuf, tabs["f_xidx"][t, d])
-                tok_f = micro_at(toks, fm)
-                mm_f = None if mmask is None else micro_at(mmask, fm)
-                y_, ce_sum, aux_f, cnt_f = chunk_fn(
-                    gather_l(layers_at(fc)), gather_s(p_shared), x_in,
-                    tok_f, mm_f, fc)
-                ce_m = _psum(ce_sum, data_axes) / jnp.maximum(
-                    count_g(tok_f, mm_f), 1.0)
-                return (y_, loss + last_l[fc] * ce_m, aux_acc + aux_f,
-                        cnt + cnt_f)
+                @jax.named_scope(scopes.TICK_F)
+                def f_on():
+                    x_in = _dyn(xbuf, tabs["f_xidx"][t, d])
+                    tok_f = micro_at(toks, fm)
+                    mm_f = None if mmask is None else micro_at(mmask, fm)
+                    y_, _, aux_f, cnt_f = chunk_fn(
+                        gather_l(layers_at(fc)), gather_s(p_shared), x_in,
+                        tok_f, mm_f, fc)
+                    return y_, aux_acc + aux_f, cnt + cnt_f
 
-            def f_off():
-                return jnp.zeros((b_loc, s_loc, h), adt), loss, aux_acc, cnt
+                def f_off():
+                    return jnp.zeros((b_loc, s_loc, h), adt), aux_acc, cnt
 
-            y, loss, aux_acc, cnt = _cond(tabs["f_act"][t, d] > 0.5, f_on,
-                                          f_off)
+                y, aux_acc, cnt = _cond(tabs["f_run"][t, d] > 0.5, f_on,
+                                        f_off)
 
             # -- issue: this tick's forward-boundary permutes (consumed
             #    after the backward below — the transfer overlaps B/W) ----
@@ -531,29 +572,28 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             bm = tabs["b_micro"][t, d]
             bc = tabs["b_chunk"][t, d]
 
+            fwd_acc = (loss, aux_acc, cnt, nf)
+
+            def add_fwd(fwd):
+                return tuple(a + b_ for a, b_ in zip(fwd_acc, fwd))
+
             if zb:
                 # zb1p's ZB-H1 split: B runs the fused chunk vjp ONCE,
                 # without slot checkpointing — the split stashes the fp32
                 # pending-dW instead of recomputing activations, so the
                 # replay the checkpoint policy would pay is gone (the
                 # memory-for-time trade estimate_memory prices via
-                # zb_pending_peak).  dx and the shared embed/head/norm
-                # grads retire here; the per-layer dW parks in its stash
-                # slot until the schedule's dedicated W tick below.
+                # zb_pending_peak); on the last model chunk that vjp is
+                # the chunk's only forward.  dx and the shared
+                # embed/head/norm grads retire here; the per-layer dW
+                # parks in its stash slot until the schedule's dedicated
+                # W tick below.
                 @jax.named_scope(scopes.TICK_B)
                 def b_on():
-                    tok_b = micro_at(toks, bm)
-                    mm_b = None if mmask is None else micro_at(mmask, bm)
                     x_sv = _dyn(xbuf, tabs["b_xidx"][t, d])
                     dy = _dyn(gbuf, tabs["b_gidx"][t, d])
-                    pl_b = layers_at(bc)
-                    _, vjp_fn = jax.vjp(
-                        lambda pl_, ps_, x_: chunk_fn(gather_l(pl_),
-                                                      gather_s(ps_), x_,
-                                                      tok_b, mm_b, bc,
-                                                      remat=False)[:3],
-                        pl_b, p_shared, x_sv)
-                    dpl, dps, dx_ = vjp_fn(_cotangents(tok_b, mm_b, bc, dy))
+                    (dpl, dps, dx_), fwd = chunk_vjp(bm, bc, x_sv, dy,
+                                                     remat=False)
                     with jax.named_scope(scopes.GRAD_ACCUM):
                         pend = jax.tree.map(
                             lambda g_: g_.astype(jnp.float32), dpl)
@@ -565,13 +605,14 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                         gsh_ = jax.tree.map(
                             lambda a, g_: a + g_.astype(jnp.float32), gsh,
                             dps)
-                    return stash_, gsh_, dx_
+                    return (stash_, gsh_, dx_) + add_fwd(fwd)
 
                 def b_off():
-                    return stash, gsh, jnp.zeros((b_loc, s_loc, h), adt)
+                    return (stash, gsh,
+                            jnp.zeros((b_loc, s_loc, h), adt)) + fwd_acc
 
-                stash, gsh, dx = _cond(tabs["b_act"][t, d] > 0.5, b_on,
-                                       b_off)
+                (stash, gsh, dx, loss, aux_acc, cnt, nf) = _cond(
+                    tabs["b_act"][t, d] > 0.5, b_on, b_off)
 
                 # -- weight-grad tick (cond-gated): the deferred half is a
                 #    pure stash -> accumulator flush, so cooldown fills
@@ -598,17 +639,9 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             else:
                 @jax.named_scope(scopes.TICK_B)
                 def b_on():
-                    tok_b = micro_at(toks, bm)
-                    mm_b = None if mmask is None else micro_at(mmask, bm)
                     x_sv = _dyn(xbuf, tabs["b_xidx"][t, d])
                     dy = _dyn(gbuf, tabs["b_gidx"][t, d])
-                    pl_b = layers_at(bc)
-                    _, vjp_fn = jax.vjp(
-                        lambda pl_, ps_, x_: chunk_fn(gather_l(pl_),
-                                                      gather_s(ps_), x_,
-                                                      tok_b, mm_b, bc)[:3],
-                        pl_b, p_shared, x_sv)
-                    dpl, dps, dx_ = vjp_fn(_cotangents(tok_b, mm_b, bc, dy))
+                    (dpl, dps, dx_), fwd = chunk_vjp(bm, bc, x_sv, dy)
                     with jax.named_scope(scopes.GRAD_ACCUM):
                         cur = jax.tree.map(lambda a: _dyn(a, bc), gl)
                         upd = jax.tree.map(
@@ -620,12 +653,14 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                         gsh_ = jax.tree.map(
                             lambda a, g_: a + g_.astype(jnp.float32), gsh,
                             dps)
-                    return gl_, gsh_, dx_
+                    return (gl_, gsh_, dx_) + add_fwd(fwd)
 
                 def b_off():
-                    return gl, gsh, jnp.zeros((b_loc, s_loc, h), adt)
+                    return (gl, gsh,
+                            jnp.zeros((b_loc, s_loc, h), adt)) + fwd_acc
 
-                gl, gsh, dx = _cond(tabs["b_act"][t, d] > 0.5, b_on, b_off)
+                gl, gsh, dx, loss, aux_acc, cnt, nf = _cond(
+                    tabs["b_act"][t, d] > 0.5, b_on, b_off)
 
             # -- consume: this tick's forward-boundary payloads (issued
             #    before the backward) land in the rings ------------------
@@ -633,7 +668,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 xbuf = write(xbuf, tabs["rfd_act"], tabs["rfd_idx"], y_dn)
             if use_f_up:
                 xbuf = write(xbuf, tabs["rfu_act"], tabs["rfu_idx"], y_up)
-            out = (xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, dx)
+            out = (xbuf, gbuf, gl, gsh, loss, aux_acc, cnt, nf, dx)
             return (out + (stash,) if zb else out), None
 
         @jax.named_scope(scopes.GRAD_ACCUM)
@@ -648,6 +683,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 jnp.zeros((), jnp.float32),
                 jnp.zeros((), jnp.float32),
                 jnp.zeros((2,), jnp.int32),          # MoE [routed, kept]
+                jnp.zeros((), jnp.int32),            # fused forwards
                 jnp.zeros((b_loc, s_loc, h), adt))    # in-flight dx carry
         if zb:
             # fp32 pending-dW stash: one chunk-shaped grad pytree per
@@ -657,7 +693,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                     lambda a: jnp.zeros((V * SS,) + a.shape[1:],
                                         jnp.float32), p_layers),)
         fin, _ = jax.lax.scan(tick, init, jnp.arange(T))
-        _, _, gl, gsh, loss, aux_acc, cnt = fin[:7]
+        _, _, gl, gsh, loss, aux_acc, cnt, nf = fin[:8]
 
         with jax.named_scope(scopes.GRAD_SYNC):
             g = dict(gsh, layers=gl)
@@ -706,12 +742,13 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             else:
                 g = jax.tree.map(lambda a: _psum(a, data_axes)[None], g)
         loss_sum = jax.lax.psum(loss + 0.01 * aux_acc, "pipe")
-        # the MoE counts of every F tick: each pipe rank holds other
+        # the MoE counts of every chunk forward: each pipe rank holds other
         # layers, each data shard other samples and, where the tokens are
         # sharded over 'model' (SP, EP), each model shard other tokens
         cnt_axes = ("pipe",) + data_axes + (
             (tp_axis,) if tp_axis and (sp or ep > 1) else ())
-        return g, loss_sum, jax.lax.psum(cnt, cnt_axes)
+        return (g, loss_sum, jax.lax.psum(cnt, cnt_axes),
+                jax.lax.psum(nf, "pipe"))
 
     data_size = 1
     for a in data_axes:
@@ -772,11 +809,11 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             return _run(stacked_l, masks_l, flags_l, firsts_l, lasts_l,
                         toks_l, rest[0] if rest else None, gdims=gdims)
 
-        g_st, loss_sum, counts = shard_map(
+        g_st, loss_sum, counts, fused = shard_map(
             inner, mesh=mesh,
             in_specs=(stage_specs, P("pipe", None, None), P("pipe", None, None),
                       P("pipe", None), P("pipe", None)) + mspecs,
-            out_specs=(stage_specs, P(), P()),
+            out_specs=(stage_specs, P(), P(), P()),
         )(stacked, masks_all, flags_all, first_all, last_all, *margs)
         with jax.named_scope(scopes.STAGE_STACK):
             grads = unstack_pipeline_grads(g_st, state.params, spec, S,
@@ -795,9 +832,12 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 new_state = _zero_constrain(new_state)
         # whole-step MoE assignments (int32; 0 for a dense model): routed,
         # and kept by the capacity (under EP: the send bucket and the
-        # receiving rank's capacity), counted in the forward ticks only
+        # receiving rank's capacity), once per chunk forward; and the
+        # chunk forwards that a backward tick's vjp ran in place of an F
+        # tick (int32; n_micro at pp = 1)
         metrics = {"loss": loss_sum / M, **opt_metrics,
-                   "moe_routed": counts[0], "moe_kept": counts[1]}
+                   "moe_routed": counts[0], "moe_kept": counts[1],
+                   "fwd_fused": fused}
         return new_state, metrics
 
     return step

@@ -46,8 +46,8 @@ from repro.core.schedules import (SCHEDULES, PipelineSchedule, TickOp,
                                   n_model_chunks, schedule_placement)
 
 __all__ = ["SCHEDULES", "PipelineSchedule", "TickOp", "ExecTables",
-           "build_exec_tables", "make_schedule", "n_model_chunks",
-           "schedule_placement"]
+           "build_exec_tables", "forward_runs", "make_schedule",
+           "n_model_chunks", "schedule_placement"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,3 +227,31 @@ def build_exec_tables(sched: PipelineSchedule) -> ExecTables:
         rgd_act=rgd_a, rgd_idx=rgd_i, rgu_act=rgu_a, rgu_idx=rgu_i,
         w_act=w_act, w_micro=w_micro, w_chunk=w_chunk,
         b_sidx=b_si, w_sidx=w_si, s_slots=ss)
+
+
+def forward_runs(tab: ExecTables, last_flag: np.ndarray) -> np.ndarray:
+    """(T, pp) float table of the forward ticks the executor runs as ticks
+    of their own: ``tab.f_act`` without the last model chunk's forwards
+    (``last_flag`` is the partition's (pp, v) flag).  That chunk's forward
+    is run by the same microbatch's backward tick, whose vjp computes it
+    anyway, so its F tick would only repeat it.
+
+    Raises where a send or receive table is active for the output of a
+    forward this drops: its payload would be zeros, not the activation.  No
+    schedule sends the last chunk's output anywhere, since nothing consumes
+    it."""
+    ranks = np.arange(tab.pp)[None, :]
+    f_run = tab.f_act * (1.0 - last_flag[ranks, tab.f_chunk])
+    dropped = (tab.f_act > 0.5) & (f_run < 0.5)
+    # sender view of the receives: down-ring lands on rank r + 1, up-ring
+    # on rank r - 1, in the tick the forward runs
+    sent = (tab.fsend_down + tab.fsend_up
+            + np.roll(tab.rfd_act, -1, axis=1)
+            + np.roll(tab.rfu_act, 1, axis=1))
+    bad = np.argwhere(dropped & (sent > 0.5))
+    if bad.size:
+        t, r = bad[0]
+        raise ValueError(
+            f"{tab.schedule}: the last model chunk's forward at tick {t} on "
+            f"rank {r} is sent on, so its F tick cannot be dropped")
+    return f_run

@@ -4,7 +4,10 @@ Compiles the pipeline executor's step for tiny dense, MoE and (four
 virtual devices, in a child process) MoE-EP meshes and reads the compiled
 HLO's ``op_name`` metadata: every scope is there, the backward tick holds
 both the replayed forward (``jvp(``) and the backward (``transpose(``),
-and the matmuls and kernels carry a layer scope.  The step's
+and the matmuls and kernels carry a layer scope.  The last model chunk's
+forward runs only inside its backward tick, so at pp = 1 no op sits under
+``tick.F``; at pp = 2 the first chunk's layers still do, and the head,
+which only the last chunk's loss reads, does not.  The step's
 ``moe_routed`` / ``moe_kept`` counters are checked against the token
 count and, at a small capacity, against a numpy count of the same
 routing for ep 1 and ep 2."""
@@ -75,10 +78,22 @@ def scope_facts(text):
                          text.splitlines() if KIND.match(line))
           if k in MATMULS]
     scoped = sum(1 for n in mm if S.layer_of(smap.get(n, "")))
+    f_layers = [S.layer_of(smap.get(n, "")) for n in mm
+                if S.TICK_F in smap.get(n, "").split("/")]
     return {"found": sorted(found),
             "b_replay": any(S.phase_of(p) == S.REPLAY for p in b_paths),
             "b_backward": any("transpose(jvp(" in p for p in b_paths),
-            "matmuls": [scoped, len(mm)]}
+            "matmuls": [scoped, len(mm)],
+            "f_matmul_layers": sorted(
+                {x for x in f_layers if x in S.MODEL_LAYERS}),
+            "f_matmuls": len(f_layers)}
+
+
+def assert_no_forward_tick(facts):
+    """pp = 1: the only model chunk is the last one, whose forward runs
+    inside the backward tick's vjp, so the step has no ``tick.F`` op."""
+    assert S.TICK_F not in facts["found"]
+    assert facts["f_matmuls"] == 0 and facts["f_matmul_layers"] == []
 
 
 def kept_ep1(eids, n_expert, cap):
@@ -128,10 +143,12 @@ def moe():
 
 def test_scopes_in_dense_step(dense):
     facts = scope_facts(dense[0])
-    assert set(facts["found"]) >= {S.TICK_F, S.TICK_B, S.EMBED, S.LAYER_SCAN,
+    assert set(facts["found"]) >= {S.TICK_B, S.EMBED, S.LAYER_SCAN,
                                    S.ATTENTION, S.MLP, S.HEAD, S.GRAD_ACCUM,
                                    S.OPTIMIZER}
+    assert_no_forward_tick(facts)
     assert S.MOE_ROUTE not in facts["found"]
+    assert dense[1]["fwd_fused"] == N_MICRO
     assert facts["b_replay"] and facts["b_backward"]
     scoped, total = facts["matmuls"]
     assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
@@ -140,7 +157,8 @@ def test_scopes_in_dense_step(dense):
 def test_scopes_in_moe_step(moe):
     facts = scope_facts(moe[0])
     assert set(facts["found"]) >= set(S.LAYERS) - {S.GRAD_SYNC} | {
-        S.TICK_F, S.TICK_B}
+        S.TICK_B}
+    assert_no_forward_tick(facts)
     assert facts["b_replay"] and facts["b_backward"]
     scoped, total = facts["matmuls"]
     assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
@@ -223,6 +241,9 @@ FOUR = textwrap.dedent("""
     _, m, _ = TS.build_step("olmoe-1b-7b", mesh_shape=(1, 2, 2), ep=2,
                             zero="os+g", batch=4, capacity_factor=2.0)
     out["dropless"] = m
+    text, m, _ = TS.build_step("qwen2-1.5b", mesh_shape=(2, 1, 1))
+    out["pp2"] = TS.scope_facts(text)
+    out["pp2_metrics"] = m
 
     # moe_forward under EP at a small capacity against the numpy count
     spec = get_spec("olmoe-1b-7b", smoke=True)
@@ -274,10 +295,23 @@ def four():
 
 def test_scopes_in_ep_step(four):
     facts = four["facts"]
-    assert set(facts["found"]) >= set(S.LAYERS) | {S.TICK_F, S.TICK_B}
+    assert set(facts["found"]) >= set(S.LAYERS) | {S.TICK_B}
+    assert_no_forward_tick(facts)
     assert facts["b_replay"] and facts["b_backward"]
     scoped, total = facts["matmuls"]
     assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
+
+
+def test_scopes_in_pp2_step(four):
+    # 1f1b over two pipe ranks: rank 0's first chunk still runs its
+    # forward in tick.F; the last chunk's does not, and nothing reads the
+    # head's output there, so no head matmul is under tick.F
+    facts = four["pp2"]
+    assert {S.TICK_F, S.TICK_B} <= set(facts["found"])
+    assert {S.ATTENTION, S.MLP} <= set(facts["f_matmul_layers"])
+    assert S.HEAD not in facts["f_matmul_layers"]
+    assert facts["b_replay"] and facts["b_backward"]
+    assert four["pp2_metrics"]["fwd_fused"] == N_MICRO
 
 
 def test_counters_ep_whole_step(four):
