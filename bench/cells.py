@@ -8,6 +8,8 @@ import json
 import pathlib
 from typing import Any, Callable, Dict, List
 
+from bench import arch
+
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH_DIR = ROOT / "bench"
 
@@ -52,7 +54,9 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
 def resolve(workload: str, root: pathlib.Path = ROOT) -> Cell:
     """The cell named ``workload``, with its configuration, traffic mix,
     limits and the metrics it reports.  Raises ``KeyError`` for a name
-    that ``BENCHMARK.json`` does not hold."""
+    that ``BENCHMARK.json`` does not hold, and where the configuration
+    names no architecture description (``bench/arch.py``) or has a key
+    that its description does not read."""
     bm = load_benchmark(root)
     cells = {w["name"]: w for w in bm["workloads"]}
     if workload not in cells:
@@ -61,6 +65,9 @@ def resolve(workload: str, root: pathlib.Path = ROOT) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bm["configs"]}
     config = _load_json(root / configs[w["config"]]["file"])
+    # the file's architecture description reads it here, before anything
+    # compiles: a file without one, or with a key it does not read, fails
+    arch.of(config, root).dims_of(config)
     return Cell(
         name=workload, chips=int(w["chips"]), config=config,
         traffic=_load_json(traffic_path(w["traffic"], root)),

@@ -1,9 +1,10 @@
 """The system under test: the 3D executor's jitted train step for a cell.
 
 Builds, from a configuration file and a traffic mix, the program's model
-(``repro.models``), mesh, ``make_pipeline_train_step`` step and the
-sharded ``TrainState`` layout it keeps resident.  Everything else the
-benchmark does (weights, batches, timing, the reference) is its own.
+(``repro.models``, with the spec its architecture description gives),
+mesh, ``make_pipeline_train_step`` step and the sharded ``TrainState``
+layout it keeps resident.  Everything else the benchmark does (weights,
+batches, timing, the reference) is its own.
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ from typing import Any, Dict, Sequence
 import jax
 import jax.numpy as jnp
 
+from bench import arch
 from bench import weights as W
 
 AXES = ("pipe", "data", "model")
@@ -22,21 +24,6 @@ AXES = ("pipe", "data", "model")
 
 def _bf16(tree):
     return jax.tree.map(lambda a: a.astype(jnp.bfloat16), tree)
-
-
-def spec_of(config: Dict[str, Any]):
-    """The program's ``ModelSpec`` with every size the file states."""
-    from repro.configs import get_spec
-    d = W.dims_of(config)
-    base = get_spec(config["repro_spec"])
-    moe = base.moe
-    if d.moe:
-        moe = dataclasses.replace(moe, n_routed=d.experts, n_active=d.top_k,
-                                  d_ff_expert=d.expert_ff)
-    return dataclasses.replace(
-        base, n_layers=d.layers, h=d.h, n_h=d.n_h, n_kv=d.n_kv,
-        d_head=d.d_head, h_ff=d.ff, vocab=d.vocab, rope_theta=d.rope_theta,
-        norm_eps=d.eps, tie_embeddings=d.tied, qkv_bias=d.qkv_bias, moe=moe)
 
 
 @dataclasses.dataclass
@@ -57,15 +44,12 @@ class Program:
         from repro.train.loop import TrainConfig
 
         tr, par = self.config["training"], self.config["parallel"]
-        self.dims = W.dims_of(self.config)
-        if self.dims.moe and self.dims.aux_coef != 0.01:
-            raise ValueError("the executor weighs the MoE aux loss by 0.01; "
-                             f"the file states {self.dims.aux_coef}")
-        self.spec = spec_of(self.config)
+        self.arch = arch.of(self.config)
+        self.dims = self.arch.dims_of(self.config)
+        self.spec, fixed = self.arch.spec_of(self.config)
         self.model = build_model(self.spec, ModelOptions(
             backend=tr["backend"], attn_impl=tr["attn_impl"],
-            capacity_factor=self.dims.capacity_factor,
-            recompute=RecomputePolicy(tr["recompute"])))
+            recompute=RecomputePolicy(tr["recompute"]), **fixed))
         shape = tuple(par["mesh"])
         n = math.prod(shape)
         if len(self.devices) < n:
@@ -81,13 +65,14 @@ class Program:
             self.model, TrainConfig(n_micro=self.n_micro, adamw=self.adamw),
             self.mesh, schedule=par["schedule"], zero=self.zero,
             sp=bool(par["sp"]), ep=self.ep)
-        # the benchmark's weight layout must be the program's parameter tree
-        mine = W.abstract(self.dims)
+        # the description's layout must be the program's parameter tree
+        mine = W.abstract(self.arch, self.dims)
         theirs = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         if jax.tree.structure(mine) != jax.tree.structure(theirs) or any(
                 (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
                 zip(jax.tree.leaves(mine), jax.tree.leaves(theirs))):
-            raise ValueError("bench/weights.py's layout is not the "
+            raise ValueError(f"the layout of bench/archs/"
+                             f"{self.config['arch']}.py is not the "
                              "program's parameter tree")
         # the working copy in bfloat16 in every leaf, as the program's step
         # leaves it (the router is float32 only before the first step), so
@@ -106,8 +91,8 @@ class Program:
 
     def make_state(self, key):
         """The TrainState from ``key``, made on the device in one call."""
-        d, init = self.dims, self._init
-        return jax.jit(lambda k: init(_bf16(W.make(d, k))),
+        a, d, init = self.arch, self.dims, self._init
+        return jax.jit(lambda k: init(_bf16(W.make(a, d, k))),
                        out_shardings=self.state_sharding)(key)
 
     def place(self, tokens):

@@ -44,7 +44,7 @@ def main(cells) -> int:
     from jax.experimental import topologies
 
     import repro.kernels.ops as K
-    from bench import reference
+    from bench import arch
     from bench import weights as W
     from bench.cells import resolve
     from bench.program import Program
@@ -73,17 +73,18 @@ def main(cells) -> int:
         est = prog.estimate()
         print(f"{name} estimate_memory {est.total / GIB:.3f} GiB", flush=True)
         # the reference's gradient of one microbatch, on one chip
-        d = W.dims_of(cell.config)
+        desc = arch.of(cell.config)
+        d = desc.dims_of(cell.config)
         one = jax.sharding.SingleDeviceSharding(topo.devices[0])
         w = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, jnp.float32, sharding=one), W.abstract(d))
+            a.shape, jnp.float32, sharding=one), W.abstract(desc, d))
         mb = shape[0] // int(cell.traffic["n_micro"])
         tok = jax.ShapeDtypeStruct((mb, shape[1]), jnp.int32, sharding=one)
         wt = jax.ShapeDtypeStruct((mb, shape[1]), jnp.float32, sharding=one)
         for precision in ("float32", "int8", "fp8"):
             with jax.default_matmul_precision("highest"):
                 g = jax.jit(jax.value_and_grad(
-                    lambda w_, t_, k_: reference.micro_loss(
+                    lambda w_, t_, k_: desc.micro_loss(
                         w_, t_, k_, d, precision))).lower(w, tok, wt).compile()
             print(f"{name} reference {precision} gradient: {_footprint(g)}",
                   flush=True)

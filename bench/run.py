@@ -14,7 +14,8 @@ reference (``reference.py``) follows the same three steps; ``check.py``
 compares the two.
 
 ``--trace 0`` prints the end-to-end metrics; ``--trace 1`` runs the window
-under the profiler and prints the per-layer metrics read from the trace
+under the profiler and prints the per-layer metrics read from the trace,
+the step's scope map and its last step's counters
 (``bench/metrics/<name>.py``), with ``busy_s``, ``window_s`` and a
 ``breakdown``.  The last line of stdout is one JSON object; the numbers
 compared, each beside its limit, are the last lines of stderr and the
@@ -49,6 +50,8 @@ os.environ.setdefault("TPU_LOG_DIR",
 
 WARM_STEPS = 3        # set-up steps: the first compiles or loads; the check
 GIB = 2 ** 30
+# the step's counters a traced run hands the readers (``ctx["counters"]``)
+COUNTERS = ("moe_routed", "moe_kept", "fwd_fused")
 
 
 def log(msg: str) -> None:
@@ -150,8 +153,8 @@ def set_up(cell, seed: int, devices, step_wrapper: Optional[Callable] = None):
     b1 = prog.adamw.b1
     m_norms = jax.jit(lambda m: jax.tree.map(
         lambda x: norm(x) / (1.0 - b1), m))
-    d = prog.dims
-    change = jax.jit(lambda k, master: change_of(d, k, master))
+    a, d = prog.arch, prog.dims
+    change = jax.jit(lambda k, master: change_of(a, d, k, master))
     losses, times, grad, grad_arrays = [], [], None, None
     for i in range(WARM_STEPS):
         t = time.perf_counter()
@@ -206,6 +209,50 @@ def window(run: Dict[str, Any], seconds: float, annotate: bool = False):
     t1 = time.perf_counter()
     run["state"] = state
     return n, t1 - t0, t0, dispatch
+
+
+def traced_window(cell, run: Dict[str, Any], seconds: float, devices,
+                  keep: Optional[Callable] = None):
+    """The window under the profiler, as ``--trace 1`` runs it.  Returns
+    the trace, the ``ctx`` the per-layer readers take and the window's
+    wall seconds.  ``ctx`` holds the step's scope map (each op name of
+    the compiled step to its ``op_name`` path, ``repro.scopes``) and the
+    counters the window's last step returned, read once the window has
+    blocked.  ``keep(dir)`` sees the profile's directory before it is
+    removed."""
+    import jax
+
+    from bench import trace as T
+    from repro.scopes import scope_map
+
+    compiled = run["compiled"]
+    smap = scope_map(compiled.as_text())
+    last: Dict[str, Any] = {}
+
+    def step(state, batch):
+        state, m = compiled(state, batch)
+        last["metrics"] = m
+        return state, m
+
+    run["compiled"] = step
+    tdir = tempfile.mkdtemp(prefix="bench-trace-")
+    try:
+        jax.profiler.start_trace(tdir)
+        with jax.profiler.TraceAnnotation("window"):
+            n, wall, _, _ = window(run, seconds, annotate=True)
+        jax.profiler.stop_trace()
+        tr = T.load(tdir)
+        if keep is not None:
+            keep(tdir)
+    finally:
+        run["compiled"] = compiled
+        shutil.rmtree(tdir, ignore_errors=True)
+    m = last["metrics"]
+    ctx = {"config": cell.config, "traffic": cell.traffic,
+           "chips": cell.chips, "steps": n,
+           "kind": devices[0].device_kind, "scopes": smap,
+           "counters": {k: int(m[k]) for k in COUNTERS if k in m}}
+    return tr, ctx, wall
 
 
 def hbm_bytes(compiled) -> int:
@@ -280,20 +327,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
         metrics = {k: {"value": values[k], "unit": units[k]}
                    for k in units}
     else:
-        from bench import trace as T
-        tdir = tempfile.mkdtemp(prefix="bench-trace-")
-        try:
-            jax.profiler.start_trace(tdir)
-            with jax.profiler.TraceAnnotation("window"):
-                n, _, _, _ = window(run, seconds, annotate=True)
-            jax.profiler.stop_trace()
-            tr = T.load(tdir)
-        finally:
-            shutil.rmtree(tdir, ignore_errors=True)
-        ctx = {"config": cell.config, "traffic": cell.traffic,
-               "chips": cell.chips, "steps": n,
-               "kind": devices[0].device_kind}
         from bench.cells import load_metric
+        tr, ctx, _ = traced_window(cell, run, seconds, devices)
+        n = ctx["steps"]
         for m in cell.per_layer:
             v = load_metric(m["name"])(tr, ctx)
             if v is not None:
@@ -301,7 +337,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
         device = {"busy_s": tr.busy_s(), "window_s": tr.window_s()}
         breakdown = tr.breakdown()
         log(f"[trace] {n} steps; busy {device['busy_s']:.4f} s of "
-            f"{device['window_s']:.4f} s; per-layer {json.dumps(metrics)}")
+            f"{device['window_s']:.4f} s; last step's counters "
+            f"{json.dumps(ctx['counters'])}; per-layer {json.dumps(metrics)}")
     dev = device_info(devices, cell.chips)
     if device:
         dev.update(device)

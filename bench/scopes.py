@@ -19,11 +19,12 @@ map:
   holds``) and by the layer and phase of the device op before it.
 
 The metrics that read scopes (``bench/metrics/step.replay_pct.py`` and
-the others) take the map from ``ctx["scopes"]`` and the step's MoE
-counters from ``ctx["counters"]``, and read nothing where ``ctx`` holds
-neither.  Run as a script, it runs one cell's traced window as
-``bench/run.py --trace 1`` does, with the map and the last step's
-counters in ``ctx``, and prints those metrics beside the cell's own::
+the others) take the map from ``ctx["scopes"]`` and the step's counters
+(``moe_routed``, ``moe_kept``, ``fwd_fused``) from ``ctx["counters"]``,
+and read nothing where ``ctx`` holds neither; ``bench/run.py --trace 1``
+hands them both (``run.traced_window``).  Run as a script, it runs one
+cell's traced window the same way and prints the metrics of ``METRICS``
+beside the cell's own, with the table and the named gaps::
 
   python3 bench/scopes.py --workload <cell> --seed <n> --seconds <s>
       [--record <dir>]
@@ -42,7 +43,6 @@ import os
 import pathlib
 import shutil
 import sys
-import tempfile
 from typing import Callable, Dict, List, Optional, Tuple
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -177,60 +177,40 @@ def _map_of_ops(trace: T.Trace, smap: Dict[str, str]) -> Dict[str, str]:
 
 def run_scoped(cell, seed: int, seconds: float, devices,
                record: Optional[str] = None) -> Dict[str, object]:
-    """One traced window of ``cell`` with the step's scope map and its
-    last step's counters; returns the cell's per-layer metrics and those
-    of ``METRICS``, the table, the named gaps and the counters."""
-    import jax
-    from jax.profiler import TraceAnnotation
-
+    """One traced window of ``cell``, as ``bench/run.py --trace 1`` runs
+    it (``run.traced_window``); returns the cell's per-layer metrics and
+    those of ``METRICS``, the table, the named gaps and the counters."""
     from bench import run as R
     from bench.cells import load_metric
 
     run = R.set_up(cell, seed, devices)
-    compiled = run["compiled"]
-    smap = S.scope_map(compiled.as_text())
-    last: Dict[str, object] = {}
+    base = os.path.join(record, f"{cell.name}.scoped") if record else None
+    host: List[T.Event] = []
 
-    def step(state, batch):
-        state, m = compiled(state, batch)
-        last["metrics"] = m
-        return state, m
-
-    run["compiled"] = step
-    tdir = tempfile.mkdtemp(prefix="bench-scopes-")
-    try:
-        jax.profiler.start_trace(tdir)
-        with TraceAnnotation("window"):
-            n, wall, _, _ = R.window(run, seconds, annotate=True)
-        jax.profiler.stop_trace()
+    def keep(tdir: str) -> None:
         path = T.find(tdir)
-        tr = T.from_file(path)
-        host = host_events(path)
-        if record:
+        host.extend(host_events(path))
+        if base:
             os.makedirs(record, exist_ok=True)
-            base = os.path.join(record, f"{cell.name}.scoped")
             with open(path, "rb") as f, \
                     gzip.open(base + ".xplane.pb.gz", "wb") as g:
                 shutil.copyfileobj(f, g)
-            with gzip.open(base + ".scopes.json.gz", "wt") as g:
-                json.dump(_map_of_ops(tr, smap), g)
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
-    m = last["metrics"]
-    counters = {k: int(m[k]) for k in ("moe_routed", "moe_kept") if k in m}
-    ctx = {"config": cell.config, "traffic": cell.traffic,
-           "chips": cell.chips, "steps": n,
-           "kind": devices[0].device_kind, "scopes": smap,
-           "counters": counters}
+
+    tr, ctx, wall = R.traced_window(cell, run, seconds, devices, keep)
+    smap = ctx["scopes"]
+    if base:
+        with gzip.open(base + ".scopes.json.gz", "wt") as g:
+            json.dump(_map_of_ops(tr, smap), g)
     names = [pl["name"] for pl in cell.per_layer] + list(METRICS)
     metrics = {name: load_metric(name)(tr, ctx) for name in names}
     tab = table(tr, smap)
     gaps = named_gaps(tr, host, smap)
     R.log(f"[trace] scopes {json.dumps(tab)}")
     R.log(f"[trace] idle gaps {json.dumps(gaps)}")
+    n = ctx["steps"]
     return {"steps": n, "window_s": tr.window_s(), "busy_s": tr.busy_s(),
             "tokens_per_s": n * run["prog"].tokens_per_step / wall,
-            "counters": counters, "metrics": metrics, "scopes": tab,
+            "counters": ctx["counters"], "metrics": metrics, "scopes": tab,
             "idle_gaps": gaps}
 
 

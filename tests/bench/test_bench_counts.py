@@ -4,6 +4,7 @@ small shape."""
 from bench import cells
 
 CONFIG = {
+    "arch": "decoder",
     "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
     "intermediate_size": 96, "vocab_size": 1000, "num_hidden_layers": 2,
     "tie_word_embeddings": True, "rope_theta": 1e4, "rms_norm_eps": 1e-6,

@@ -5,10 +5,11 @@ benchmark's contract asks of it."""
 import json
 import re
 
+import jax
 import pytest
 
-from bench import cells, check
-from bench.weights import dims_of
+from bench import arch, cells, check
+from bench import weights as W
 
 BM = cells.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -46,8 +47,11 @@ def test_config_file(c):
         config = json.load(f)
     assert config["name"] == c["name"] and config["source"] == c["source"]
     assert sorted(config["reduced"]) == sorted(c["reduced"])
-    d = dims_of(config)
-    assert d.h % d.n_h == 0 and d.n_h % d.n_kv == 0
+    # its description reads it, and lays out weights for it
+    desc = arch.of(config)
+    d = desc.dims_of(config)
+    assert d.h > 0 and d.vocab > 0
+    assert jax.tree.leaves(W.abstract(desc, d))
     assert any(w["config"] == c["name"] for w in BM["workloads"])
 
 

@@ -124,7 +124,7 @@ def test_readers_on_hand_made_events(name, want):
 
 @pytest.mark.parametrize("name", BS.METRICS)
 def test_readers_read_nothing_without_the_program_data(name):
-    # bench/run.py as it stands hands no scope map and no counters
+    # a ctx with no scope map and no counters
     ctx = {"config": {}, "traffic": {}, "chips": 1, "steps": 1,
            "kind": "TPU v5 lite"}
     assert _read(name, ctx) is None
@@ -202,8 +202,9 @@ def test_recorded_replay_share(recorded):
 
 def test_run_scoped_on_the_cpu(tiny, tmp_path):
     """The script's path at a small size: the step's counters reach the
-    result (one MoE layer, top-2: every token of the step routed twice)
-    and ``--record`` writes the trace and its map.  The CPU's trace has no
+    result (one MoE layer, top-2: every token of the step routed twice;
+    one fused forward per microbatch) and ``--record`` writes the trace
+    and its map.  The CPU's trace has no
     TPU plane, so nothing is read from it."""
     import jax
     cell = tiny("moe")
@@ -213,6 +214,8 @@ def test_run_scoped_on_the_cpu(tiny, tmp_path):
     assert out["counters"]["moe_routed"] == \
         int(tr["global_batch"]) * int(tr["seq_len"]) * 2
     assert 0 < out["counters"]["moe_kept"] <= out["counters"]["moe_routed"]
+    # at pp = 1 the backward tick runs every microbatch's only forward
+    assert out["counters"]["fwd_fused"] == int(tr["n_micro"])
     assert out["metrics"]["step.replay_pct"] is None
     assert out["metrics"]["moe.dropped_pct"] == pytest.approx(
         100 * (1 - out["counters"]["moe_kept"]

@@ -1,6 +1,10 @@
-"""The reduction from a profiler trace to the per-layer metrics, on a trace
+"""The reduction from a profiler trace to the per-layer metrics, on traces
 recorded on one TPU v5e chip (three steps of ``olmoe.1l.s4096.m4``'s
-window, ``bench/testdata``) and on hand-made events."""
+window, and one scoped step with its scope map; ``bench/testdata``) and
+on hand-made events."""
+
+import gzip
+import json
 
 import pytest
 
@@ -35,18 +39,40 @@ def test_recorded_kernels(recorded):
     assert len(gmm[0]) == 3 * 4 * 2 * 3
 
 
-def test_recorded_metrics(recorded):
+SCOPED = cells.ROOT / "bench" / "testdata" / \
+    "olmoe.1l.s4096.m4.scoped.xplane.pb.gz"
+SCOPED_MAP = cells.ROOT / "bench" / "testdata" / \
+    "olmoe.1l.s4096.m4.scoped.scopes.json.gz"
+# the recording keeps no counters: these are the last step's of a 23-step
+# window of the same cell on the same chip (4 x 4096 tokens, top-8)
+COUNTERS = {"moe_routed": 131072, "moe_kept": 56277}
+
+
+def test_recorded_metrics():
+    """Every per-layer metric of the cell, on one scoped step recorded on
+    one TPU v5e chip, with its scope map, as a ``--trace 1`` run hands
+    them the readers."""
+    with gzip.open(SCOPED_MAP, "rt") as f:
+        smap = json.load(f)
+    scoped = T.from_file(str(SCOPED))
     cell = cells.resolve("olmoe.1l.s4096.m4")
     ctx = {"config": cell.config, "traffic": cell.traffic, "chips": 1,
-           "steps": 3, "kind": "TPU v5 lite"}
-    got = {m["name"]: cells.load_metric(m["name"])(recorded, ctx)
+           "steps": 1, "kind": "TPU v5 lite", "scopes": smap,
+           "counters": COUNTERS}
+    got = {m["name"]: cells.load_metric(m["name"])(scoped, ctx)
            for m in cell.per_layer}
     for name, v in got.items():
         assert v is not None and 0 < v <= 100, (name, v)
-    # the step at 0.44 s: about a fifth of the bf16 peak
+    # the step at 0.45 s: about a fifth of the bf16 peak
     assert 15 < got["step_mfu"] < 25
     # the static-capacity kernel can read at most 1 / capacity factor
     assert got["gmm_fwd_roofline"] <= 100 / 1.25
+    # the scope shares, as read when the step was recorded: 20.46, 26.34,
+    # 36.18 %
+    assert 18 < got["attention.device_pct"] < 23
+    assert 24 < got["head.device_pct"] < 29
+    assert 34 < got["moe.experts_pct"] < 39
+    assert got["moe.dropped_pct"] == 100 * (1 - 56277 / 131072)
 
 
 def test_recorded_breakdown(recorded):
