@@ -18,6 +18,7 @@ from repro.core.notation import ModelSpec
 ARCHS: List[str] = [
     "deepseek_v3",        # the paper's reference model
     "deepseek_v2",        # paper also covers v2
+    "deepseek_v2_lite",   # V2's small sibling: no query LoRA, YaRN
     "olmoe_1b_7b",
     "qwen2_vl_72b",
     "minitron_4b",
@@ -48,6 +49,7 @@ _ALIASES.update({
     "qwen2-1.5b": "qwen2_1_5b",
     "deepseek-v3": "deepseek_v3",
     "deepseek-v2": "deepseek_v2",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
 })
 

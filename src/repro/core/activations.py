@@ -117,7 +117,9 @@ def mla_activation_bytes(spec: ModelSpec, b: int, s: int, *, tp: int, sp: int,
       M1 = 4bsh/sp + 2bs(d_cq+d_c) + 4bs(d_h+d_hr)n_h/tp + 2bs d_h n_h/tp
            + 5 b n_h s^2/tp + 2bs d_h n_h/tp + bsh/sp
     The 2bs(d_cq+d_c) latent tensors are NOT divided by sp because the down
-    projections are replicated (paper).  AC Full: 2bsh/sp.
+    projections are replicated (paper); without a query LoRA (``d_cq``
+    None) there is no query latent and the term is 2bs·d_c.  AC Full:
+    2bsh/sp.
 
     ``attn_impl`` in ``FLASH_ATTN_IMPLS`` drops exactly the 5·b·n_h·s²
     score/softmax/mask term at AC-None — the tiled kernel keeps the s²
@@ -136,7 +138,7 @@ def mla_activation_bytes(spec: ModelSpec, b: int, s: int, *, tp: int, sp: int,
         // _head_shard_or_warn(spec.n_h, tp, "n_h")
     none_total = (
         4 * b * s * spec.h // sp
-        + 2 * b * s * (m.d_cq + m.d_c)
+        + 2 * b * s * ((m.d_cq or 0) + m.d_c)
         + 4 * b * s * (m.d_h + m.d_hr) * spec.n_h // tp_c
         + 2 * b * s * m.d_v * spec.n_h // tp_c
         + scores
@@ -169,7 +171,7 @@ def moe_activation_bytes(spec: ModelSpec, b: int, s: int, *, sp: int, cp: int,
     sp = _seq_shard_or_warn(s, sp)
     if recompute == RecomputePolicy.FULL:
         return b * s * spec.h + 2 * b * s * e.n_active
-    n_local = e.n_routed // _shard_or_warn(e.n_routed, ep, "n_routed (EP)")
+    n_local = e.held // _shard_or_warn(e.held, ep, "n_routed (EP)")
     e_token = b * s * e.n_active / e.n_routed
     routed = n_local * (3 * e_token * spec.h + 8 * e_token * e.d_ff_expert)
     shared = e.n_shared * (3 * b * s * spec.h + 8 * b * s * e.d_ff_expert)

@@ -45,7 +45,10 @@ class FamilyKind(enum.Enum):
 class MLASpec:
     """Multi-head latent attention dimensions (paper Table 1)."""
 
-    d_cq: int = 1536       # query compression dim (q_lora_rank)
+    # query compression dim (q_lora_rank); None: no query LoRA, one W^Q
+    # projects the hidden state to every head's (d_h + d_hr) query
+    # (DeepSeek-V2-Lite)
+    d_cq: Optional[int] = 1536
     d_c: int = 512         # key-value compression dim (kv_lora_rank)
     d_h: int = 128         # qk_nope_head_dim
     d_hr: int = 64         # qk_rope_head_dim
@@ -63,6 +66,46 @@ class MoESpec:
     # layers [0, first_k_dense) use a dense FFN instead of MoE (DeepSeek: 3).
     first_k_dense: int = 0
     router_bias: bool = False
+    # experts [0, n_held) of the n_routed live in this layer (the chip's
+    # share under expert parallelism); the router still scores n_routed.
+    # None: every expert (``held``)
+    n_held: Optional[int] = None
+    norm_topk: bool = True     # renormalise the top-k gates to sum to one
+    aux_coef: float = 0.01     # weight of the load-balance loss
+
+    def __post_init__(self):
+        if self.n_held is not None and not 0 < self.n_held <= self.n_routed:
+            raise ValueError(f"n_held={self.n_held} must be in "
+                             f"[1, n_routed={self.n_routed}]")
+
+    @property
+    def held(self) -> int:
+        """The routed experts this layer holds."""
+        return self.n_routed if self.n_held is None else self.n_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNSpec:
+    """YaRN rope scaling (arXiv:2309.00071, DeepSeek-V2's ``rope_scaling``).
+
+    The softmax scale takes ``mscale(factor, mscale_all_dim)²``; cos and
+    sin would take the ratio ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, which is 1 where the two coefficients are equal, as
+    in DeepSeek-V2 and V2-Lite, the only setting built."""
+
+    factor: float                  # context extension factor
+    original_max_position: int     # the length the rope was trained at
+    beta_fast: float = 32.0        # rotations above which dims extrapolate
+    beta_slow: float = 1.0         # rotations below which dims interpolate
+    mscale: float = 1.0            # rope-amplitude mscale coefficient
+    mscale_all_dim: float = 1.0    # softmax-scale mscale coefficient
+
+    def __post_init__(self):
+        if self.mscale != self.mscale_all_dim:
+            raise ValueError(
+                f"YaRN with mscale {self.mscale} != mscale_all_dim "
+                f"{self.mscale_all_dim} scales cos and sin, which is not "
+                "built")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,6 +155,7 @@ class ModelSpec:
     qkv_bias: bool = False
     norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    yarn: Optional[YaRNSpec] = None
     # Sliding-window decode variant (enables long_500k for full-attention archs).
     sliding_window: Optional[int] = None
     max_seq_len: int = 32768
@@ -128,6 +172,12 @@ class ModelSpec:
     @property
     def attn_free(self) -> bool:
         return self.attention == AttentionKind.NONE
+
+    @property
+    def aux_coef(self) -> float:
+        """Weight of the MoE load-balance loss in the training loss (a
+        dense model's aux is zero; it keeps the 0.01 default)."""
+        return self.moe.aux_coef if self.is_moe else MoESpec.aux_coef
 
     def moe_layer_indices(self) -> Tuple[int, ...]:
         if not self.is_moe:
@@ -147,20 +197,23 @@ class ModelSpec:
         if self.attention == AttentionKind.MLA:
             m = self.mla
             tp_split = (
-                m.d_h * self.n_h * m.d_cq        # W^UQ
-                + m.d_h * self.n_h * m.d_c       # W^UK
+                m.d_h * self.n_h * m.d_c         # W^UK
                 + m.d_v * self.n_h * m.d_c       # W^UV
                 + self.h * m.d_v * self.n_h      # W^O
             )
             replicated = (
-                m.d_cq * self.h                  # W^DQ
-                + m.d_c * self.h                 # W^DKV
-                + m.d_hr * self.n_h * m.d_cq     # W^QR
+                m.d_c * self.h                   # W^DKV
                 + m.d_hr * self.h                # W^KR
             )
+            if m.d_cq is None:                   # W^Q
+                tp_split += self.h * self.n_h * (m.d_h + m.d_hr)
+            else:
+                tp_split += m.d_h * self.n_h * m.d_cq         # W^UQ
+                replicated += (m.d_cq * self.h                # W^DQ
+                               + m.d_hr * self.n_h * m.d_cq)  # W^QR
             total = tp_split + replicated
             if include_qk_norm:
-                total += m.d_cq + m.d_c          # q/kv RMSNorm (paper Table 3)
+                total += self.mla_norm_params()  # q/kv RMSNorm (paper Table 3)
             return total
         if self.attention == AttentionKind.NONE:
             return 0
@@ -186,7 +239,7 @@ class ModelSpec:
             return 0
         e = self.moe
         router = e.n_routed * self.h + (e.n_routed if e.router_bias else 0)
-        experts = 3 * self.h * e.d_ff_expert * (e.n_routed + e.n_shared)
+        experts = 3 * self.h * e.d_ff_expert * (e.held + e.n_shared)
         return router + experts
 
     def moe_active_params_per_layer(self) -> int:
@@ -210,10 +263,15 @@ class ModelSpec:
         conv = s.conv_kernel * d if s.conv_kernel else 0
         return proj + decay + tokenshift + conv
 
+    def mla_norm_params(self) -> int:
+        """MLA's latent RMSNorms: the query's (d_cq, where the query is
+        compressed) and the key-value latent's (d_c)."""
+        return (self.mla.d_cq or 0) + self.mla.d_c
+
     def norm_params_per_layer(self) -> int:
         n = 2 * self.h
         if self.attention == AttentionKind.MLA:
-            n += self.mla.d_cq + self.mla.d_c   # counted in LN row by the paper
+            n += self.mla_norm_params()   # counted in LN row by the paper
         return n
 
     def embedding_params(self) -> int:

@@ -45,7 +45,7 @@ def ln_params_paper(spec: ModelSpec) -> int:
     """LN row of Table 3: 2*h + d_cq + d_c (double-counts the qk norms)."""
     n = 2 * spec.h
     if spec.attention == AttentionKind.MLA:
-        n += spec.mla.d_cq + spec.mla.d_c
+        n += spec.mla_norm_params()
     return n
 
 
@@ -57,7 +57,7 @@ def table3_rows(spec: ModelSpec) -> List[LayerRow]:
     ln = ln_params_paper(spec)
     dense_mlp = spec.dense_mlp_params_per_layer()
     gate = spec.moe.n_routed * spec.h
-    experts = 3 * spec.h * spec.moe.d_ff_expert * (spec.moe.n_routed + spec.moe.n_shared)
+    experts = 3 * spec.h * spec.moe.d_ff_expert * (spec.moe.held + spec.moe.n_shared)
     emb = spec.embedding_params()
     k = spec.moe.first_k_dense
     l = spec.n_layers
@@ -123,7 +123,7 @@ def layer_params_paper(spec: ModelSpec, layer_idx: int) -> int:
     p = mla + ln
     if spec.is_moe and layer_idx in spec.moe_layer_indices():
         p += spec.moe.n_routed * spec.h
-        p += 3 * spec.h * spec.moe.d_ff_expert * (spec.moe.n_routed + spec.moe.n_shared)
+        p += 3 * spec.h * spec.moe.d_ff_expert * (spec.moe.held + spec.moe.n_shared)
     else:
         p += spec.dense_mlp_params_per_layer()
     if layer_idx == 0:
@@ -194,19 +194,24 @@ def attn_tp_split(spec: ModelSpec, tp: int) -> Tuple[int, int]:
     """(tp_partitioned_per_rank, replicated) attention params for one layer.
 
     MLA follows Megatron: W^UQ/W^UK/W^UV/W^O split, W^DQ/W^DKV/W^QR/W^KR
-    replicated (paper §3.2).  GQA/MQA: q/k/v/o sharded on the head-columns
+    replicated (paper §3.2); without a query LoRA the one W^Q is split by
+    heads like W^UQ.  GQA/MQA: q/k/v/o sharded on the head-columns
     dim when divisible (TPU runtime semantics — columns, not whole heads).
     """
     if spec.attention == AttentionKind.NONE:
         return 0, 0
     if spec.attention == AttentionKind.MLA:
         m = spec.mla
-        split = (_shard(m.d_h * spec.n_h * m.d_cq, tp, m.d_h * spec.n_h)
-                 + _shard(m.d_h * spec.n_h * m.d_c, tp, m.d_h * spec.n_h)
+        split = (_shard(m.d_h * spec.n_h * m.d_c, tp, m.d_h * spec.n_h)
                  + _shard(m.d_v * spec.n_h * m.d_c, tp, m.d_v * spec.n_h)
                  + _shard(spec.h * m.d_v * spec.n_h, tp, m.d_v * spec.n_h))
-        repl = (m.d_cq * spec.h + m.d_c * spec.h
-                + m.d_hr * spec.n_h * m.d_cq + m.d_hr * spec.h)
+        repl = m.d_c * spec.h + m.d_hr * spec.h
+        if m.d_cq is None:
+            q_cols = spec.n_h * (m.d_h + m.d_hr)
+            split += _shard(spec.h * q_cols, tp, q_cols)
+        else:
+            split += _shard(m.d_h * spec.n_h * m.d_cq, tp, m.d_h * spec.n_h)
+            repl += m.d_cq * spec.h + m.d_hr * spec.n_h * m.d_cq
         return split, repl
     qdim = spec.n_h * spec.d_head
     kvdim = spec.n_kv * spec.d_head
@@ -261,7 +266,7 @@ def device_params(spec: ModelSpec, cfg: ParallelConfig,
             ssm += proj + decay + rest
         if spec.is_moe and l in spec.moe_layer_indices():
             router += spec.moe.n_routed * spec.h
-            n_local = spec.moe.n_routed // cfg.ep
+            n_local = spec.moe.held // cfg.ep
             per_expert = 3 * spec.h * spec.moe.d_ff_expert // cfg.etp
             # shared experts replicated across EP ranks (paper §3.3)
             experts += (n_local + spec.moe.n_shared) * per_expert

@@ -8,12 +8,13 @@ Dtype discipline (paper Table 7): weights/activations bf16, reductions
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.notation import MlpKind, ModelSpec
+from repro.core.notation import MlpKind, ModelSpec, YaRNSpec
 
 Params = Dict[str, jnp.ndarray]
 
@@ -51,11 +52,39 @@ def rmsnorm(p: Params, x: jnp.ndarray, eps: float = 1e-6,
 # Rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(d: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's temperature factor ``0.1·mscale·ln(factor) + 1`` (1 where the
+    context is not extended)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_dim(rotations: float, d: int, theta: float, length: int) -> float:
+    """The rotary dim whose wavelength fits ``rotations`` times into the
+    original ``length``."""
+    return d * math.log(length / (rotations * 2 * math.pi)) \
+        / (2 * math.log(theta))
+
+
+def rope_freqs(d: int, theta: float,
+               yarn: Optional[YaRNSpec] = None) -> jnp.ndarray:
+    """Inverse frequencies of the d/2 rotary pairs.  Under YaRN the pairs
+    that turn fewer than ``beta_slow`` times over the original length are
+    interpolated (frequency / factor), those that turn more than
+    ``beta_fast`` times are kept, with a linear ramp between."""
+    base = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if yarn is None:
+        return base
+    n = yarn.original_max_position
+    low = max(math.floor(_yarn_dim(yarn.beta_fast, d, theta, n)), 0)
+    high = min(math.ceil(_yarn_dim(yarn.beta_slow, d, theta, n)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return base / yarn.factor * ramp + base * (1.0 - ramp)
+
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float = 10000.0) -> jnp.ndarray:
+               theta: float = 10000.0,
+               yarn: Optional[YaRNSpec] = None) -> jnp.ndarray:
     """x: (..., seq, n_heads, d); positions: (..., seq).
 
     M-RoPE note (Qwen2-VL): multimodal rotary splits the head dim into
@@ -65,7 +94,7 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
     recorded in DESIGN.md as a frontend-stub consequence.
     """
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                              # (d/2,)
+    freqs = rope_freqs(d, theta, yarn)                        # (d/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., s, d/2)
     cos = jnp.cos(angles)[..., None, :]                        # broadcast heads
     sin = jnp.sin(angles)[..., None, :]

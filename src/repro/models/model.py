@@ -138,7 +138,7 @@ class Model:
         if mask.shape == tokens.shape:
             mask = mask[:, 1:]
         ce = jnp.sum((logz - gold) * mask) / jnp.maximum(mask.sum(), 1.0)
-        total = ce + 0.01 * aux
+        total = ce + self.spec.aux_coef * aux
         return total, {"ce": ce, "aux": aux, "loss": total}
 
     # ------------------------------------------------------------------

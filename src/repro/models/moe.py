@@ -9,6 +9,12 @@ Matches the paper's accounting: balanced load gives E_token = b·s·N_r/N
 tokens per expert (capacity_factor=1.0 reproduces §5.2 exactly; default 1.25
 gives headroom like production routers).  Shared experts process every token
 and are replicated across EP ranks (paper §3.3).
+
+A layer may hold only experts ``[0, n_held)`` of the ``n_routed`` its router
+scores (``MoESpec.n_held``: one chip's share of an expert-parallel layer,
+run without the exchange): the assignments to the other experts take no
+slot and add nothing, and the capacity and the load-balance loss stay
+those of all ``n_routed``.
 """
 
 from __future__ import annotations
@@ -33,7 +39,8 @@ class MoEOutput(NamedTuple):
     # over the token-sharding axis.
     router_probs: jnp.ndarray
     # int32 counts of (token, expert) assignments: ``routed`` of this
-    # rank's routed token set; ``kept`` those that reached an expert (past
+    # rank's routed token set (to the held experts, where the layer holds
+    # a share of them); ``kept`` those that reached an expert (past
     # the capacity; under EP past the send bucket and the receiving
     # rank's capacity, counted where the expert runs).  Summed over the
     # token-sharding and data axes they are the whole microbatch's.
@@ -45,13 +52,14 @@ def moe_init(key: jax.Array, spec: ModelSpec, dtype=jnp.bfloat16) -> Params:
     e = spec.moe
     kr, ke, ks = jax.random.split(key, 3)
     kg, ku, kd = jax.random.split(ke, 3)
-    E, h, f = e.n_routed, spec.h, e.d_ff_expert
+    E, H, h, f = e.n_routed, e.held, spec.h, e.d_ff_expert
     p = {
         "router": dense_init(kr, (h, E), jnp.float32, scale=h ** -0.5),
-        # stacked expert weights: leading dim = expert (EP-sharded)
-        "we_gate": dense_init(kg, (E, h, f), dtype),
-        "we_up": dense_init(ku, (E, h, f), dtype),
-        "we_down": dense_init(kd, (E, f, h), dtype),
+        # stacked expert weights of the held experts: leading dim = expert
+        # (EP-sharded)
+        "we_gate": dense_init(kg, (H, h, f), dtype),
+        "we_up": dense_init(ku, (H, h, f), dtype),
+        "we_down": dense_init(kd, (H, f, h), dtype),
     }
     if e.n_shared:
         p["shared"] = mlp_init(ks, spec, f * e.n_shared, dtype)
@@ -62,19 +70,21 @@ def _route(router_w: jnp.ndarray, spec: ModelSpec, xt: jnp.ndarray,
            router_impl: str) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Route flat tokens (T, h) -> (probs (T, E) fp32, gates (T, K) fp32,
     eids (T, K) int32).  DeepSeek-v3 sigmoid scoring + top-k renorm, or
-    classic top-k softmax (OLMoE/Qwen3).  Shared by the scatter, EP-a2a and
-    GSPMD-a2a dispatch paths so routing can never drift between them."""
+    classic top-k softmax (OLMoE/Qwen3); ``norm_topk=False`` keeps the
+    top-k softmax gates as they are (DeepSeek-V2).  Shared by the scatter,
+    EP-a2a and GSPMD-a2a dispatch paths so routing can never drift between
+    them."""
     e = spec.moe
     logits = xt.astype(jnp.float32) @ router_w
     if router_impl == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         gate_vals, eids = jax.lax.top_k(scores, e.n_active)
-        gates = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
         probs = scores / (scores.sum(-1, keepdims=True) + 1e-20)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, eids = jax.lax.top_k(probs, e.n_active)
-        gates = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+    gates = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20) \
+        if e.norm_topk else gate_vals
     return probs, gates, eids
 
 
@@ -171,7 +181,7 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     e = spec.moe
     b, s, h = x.shape
     T = b * s
-    E, K = e.n_routed, e.n_active
+    E, K, H = e.n_routed, e.n_active, e.held
     xt = x.reshape(T, h)
 
     if ep > 1:
@@ -203,11 +213,20 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
         flat_eids = eids.reshape(T * K)
         pos, _ = _positions_in_expert(flat_eids, E)
         keep = (pos < C)
+        routed = jnp.int32(T * K)
+        if H < E:
+            # only the held experts' assignments are routed here; each of
+            # the others has its own expert's positions, so it takes no
+            # held slot, and it writes and reads nothing (keep is 0)
+            held = flat_eids < H
+            keep = keep & held
+            routed = jnp.sum(held, dtype=jnp.int32)
+            flat_eids = jnp.minimum(flat_eids, H - 1)
         pos_c = jnp.minimum(pos, C - 1)
 
-        # dispatch: scatter kept tokens into the (E, C, h) buffer
+        # dispatch: scatter kept tokens into the (H, C, h) buffer
         src = jnp.repeat(xt, K, axis=0) * keep[:, None].astype(x.dtype)
-        buf = jnp.zeros((E, C, h), x.dtype).at[flat_eids, pos_c].add(src)
+        buf = jnp.zeros((H, C, h), x.dtype).at[flat_eids, pos_c].add(src)
         if tp_f is not None:
             buf = tp_f(buf)
 
@@ -229,13 +248,12 @@ def moe_forward(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
         y = y_pairs.reshape(T, K, h).sum(axis=1)
 
     if e.n_shared:
-        with jax.named_scope(S.MOE_EXPERTS):
+        with jax.named_scope(S.MOE_SHARED):
             xs = tp_f(xt) if tp_f is not None else xt
             ys = mlp_apply(p["shared"], spec, xs)
             y = y + (tp_g(ys) if tp_g is not None else ys)
     return MoEOutput(y=y.reshape(b, s, h), aux_loss=aux, router_probs=probs,
-                     routed=jnp.int32(T * K),
-                     kept=jnp.sum(keep, dtype=jnp.int32))
+                     routed=routed, kept=jnp.sum(keep, dtype=jnp.int32))
 
 
 def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
@@ -316,7 +334,7 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
     # overlap structure at slot granularity).
     ys = None
     if e.n_shared:
-        with jax.named_scope(S.MOE_EXPERTS):
+        with jax.named_scope(S.MOE_SHARED):
             xs = tp_f(xt_full) if tp_f is not None else xt_full
             ys = mlp_apply(p["shared"], spec, xs)
             if tp_g is not None:
@@ -370,22 +388,15 @@ def _moe_forward_ep(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
 def moe_forward_dense_ref(p: Params, spec: ModelSpec, x: jnp.ndarray, *,
                           router_impl: str = "softmax") -> jnp.ndarray:
     """Dropless dense reference: every token runs through its top-k experts
-    via full (T, E) weighting.  O(T·E·h·f) — for tests on tiny sizes only."""
+    via full (T, E) weighting, of which the held experts' part.
+    O(T·E·h·f) — for tests on tiny sizes only."""
     e = spec.moe
     b, s, h = x.shape
     T = b * s
     xt = x.reshape(T, h)
-    logits = xt.astype(jnp.float32) @ p["router"]
-    if router_impl == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        gate_vals, eids = jax.lax.top_k(scores, e.n_active)
-        gates = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
-    else:
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, eids = jax.lax.top_k(probs, e.n_active)
-        gates = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-20)
+    _, gates, eids = _route(p["router"], spec, xt, router_impl)
     w = jnp.zeros((T, e.n_routed), jnp.float32)
-    w = w.at[jnp.arange(T)[:, None], eids].set(gates)
+    w = w.at[jnp.arange(T)[:, None], eids].set(gates)[:, :e.held]
     # per-expert dense pass
     a = jax.nn.silu(jnp.einsum("th,ehf->etf", xt, p["we_gate"]))
     a = a * jnp.einsum("th,ehf->etf", xt, p["we_up"])

@@ -24,18 +24,18 @@ Two views of the same partition are provided:
 
 * **Chunk-stacked (SPMD) layout** (``stack_pipeline_params`` /
   ``unstack_pipeline_grads`` + ``pipeline_stage_apply``): every layer leaf
-  gains leading ``(pp, n_chunks, l_max)`` dims with the ``pp`` dim sharded
-  over the ``pipe`` mesh axis, chunk layer stacks padded to the widest chunk
-  (masked identity slots) and a *union* slot structure (a slot carries both
-  the dense-MLP and MoE subtrees when the model mixes kinds; a per-slot
-  flag selects).  Embedding / final-norm / head keep one row per rank, zero
+  gains leading ``(pp, n_chunks, l)`` dims with the ``pp`` dim sharded
+  over the ``pipe`` mesh axis, one stack per layer kind (the dense layers,
+  then the MoE layers: ``KINDS``), each chunk's stack of a kind padded to
+  the widest (masked identity slots), so a slot holds and computes only its
+  own kind.  Embedding / final-norm / head keep one row per rank, zero
   except on ranks whose chunks own them.  This is what the schedule-driven
   executor (``train.pipeline_loop``) runs under ``shard_map`` — one
   program, rank identity = ``lax.axis_index('pipe')``, the active chunk per
   tick read from the schedule's static tables.
 
-The stacked layout trades memory for SPMD uniformity (padded slots, the
-unused half of mixed dense/MoE slots, zero embed rows on interior ranks);
+The stacked layout trades memory for SPMD uniformity (padded slots, a
+kind's pads on chunks that lack it, zero embed rows on interior ranks);
 the per-rank dry-run path has no such padding, so memory validation always
 uses the heterogeneous view.
 """
@@ -89,7 +89,6 @@ class StagePartition:
     l_max: int                        # widest stage (slot count of the SPMD view)
     idx: np.ndarray                   # (pp, l_max) global layer id; pads repeat
     mask: np.ndarray                  # (pp, l_max) f32: 1 real slot, 0 pad
-    moe_flag: np.ndarray              # (pp, l_max) f32: 1 MoE layer, 0 dense
     stage_of: np.ndarray              # (n_layers,) stage owning each layer
     slot_of: np.ndarray               # (n_layers,) slot within that stage
 
@@ -102,7 +101,6 @@ def partition(spec: ModelSpec, pp: int) -> StagePartition:
     l_max = max(len(ls) for ls in stages)
     idx = np.zeros((pp, l_max), np.int32)
     mask = np.zeros((pp, l_max), np.float32)
-    moe_flag = np.zeros((pp, l_max), np.float32)
     stage_of = np.zeros(spec.n_layers, np.int32)
     slot_of = np.zeros(spec.n_layers, np.int32)
     for i, ls in enumerate(stages):
@@ -111,22 +109,41 @@ def partition(spec: ModelSpec, pp: int) -> StagePartition:
             idx[i, j] = l
             if j < len(ls):
                 mask[i, j] = 1.0
-                moe_flag[i, j] = float(l >= n_dense)
                 stage_of[l] = i
                 slot_of[l] = j
     return StagePartition(pp=pp, n_layers=spec.n_layers, n_dense=n_dense,
                           stages=stages, l_max=l_max, idx=idx, mask=mask,
-                          moe_flag=moe_flag, stage_of=stage_of,
-                          slot_of=slot_of)
+                          stage_of=stage_of, slot_of=slot_of)
+
+
+# the Model's layer groups (``Model.init``), in model order: the dense
+# layers are a prefix
+KINDS = ("dense_layers", "moe_layers")
+
+
+@dataclasses.dataclass(frozen=True)
+class KindSlots:
+    """One layer kind's slots in the chunk-stacked layout: every chunk
+    holds ``l`` slots of the kind, padded where it has fewer (a chunk
+    without the kind is all pads).  All arrays are numpy (static schedule
+    data)."""
+
+    kind: str                         # "dense_layers" | "moe_layers"
+    idx: np.ndarray                   # (pp, v, l) index into the kind's group
+    mask: np.ndarray                  # (pp, v, l) f32: 1 real, 0 pad
+    # (pp, v, l) f32: 1 on the MoE group's real slots, 0 in the dense
+    # group; weighs a slot's MoE output, aux loss and counts
+    moe_flag: np.ndarray
+    # per layer of the group, every (rank, chunk, slot) holding it
+    occurrences: Tuple[Tuple[Tuple[int, int, int], ...], ...]
 
 
 @dataclasses.dataclass(frozen=True)
 class ChunkedPartition:
-    """Schedule-aware layer→(rank, chunk) assignment plus the index/mask
-    arrays the chunk-stacked SPMD layout derives from it.  All arrays are
-    numpy (static schedule data).  ``occurrences[l]`` lists every
-    (rank, chunk, slot) holding global layer ``l`` — exactly one entry per
-    layer except under dualpipe, where every layer lives on two ranks."""
+    """Schedule-aware layer→(rank, chunk) assignment plus the slot arrays
+    the chunk-stacked SPMD layout derives from it, one ``KindSlots`` per
+    layer kind the model has.  Each layer occurs once in its kind's slots,
+    except under dualpipe, where every layer lives on two ranks."""
 
     pp: int
     n_chunks: int                     # v, local chunks per rank
@@ -136,13 +153,34 @@ class ChunkedPartition:
     schedule: str
     chunks: Tuple[Tuple[Tuple[int, ...], ...], ...]   # (pp, v) layer tuples
     placement: Tuple[Tuple[int, ...], ...]            # (pp, v) model chunk id
-    l_max: int                        # widest chunk (slot count per chunk)
-    idx: np.ndarray                   # (pp, v, l_max) global layer id
-    mask: np.ndarray                  # (pp, v, l_max) f32: 1 real, 0 pad
-    moe_flag: np.ndarray              # (pp, v, l_max) f32
+    slots: Tuple[KindSlots, ...]      # the kinds present, in model order
     first_flag: np.ndarray            # (pp, v) f32: chunk is model chunk 0
     last_flag: np.ndarray             # (pp, v) f32: chunk is the last
-    occurrences: Tuple[Tuple[Tuple[int, int, int], ...], ...]
+
+
+def _kind_slots(kind: str, lo: int, hi: int, chunks) -> KindSlots:
+    """Slots of the layers [lo, hi) of one kind: each chunk's own layers
+    of the kind in order, then pads that repeat the chunk's last one (or,
+    where the chunk has none, the kind's layer nearest to the chunk)."""
+    pp, v = len(chunks), len(chunks[0])
+    own = [[[l for l in chunks[r][c] if lo <= l < hi] for c in range(v)]
+           for r in range(pp)]
+    width = max(len(ls) for row in own for ls in row)
+    idx = np.zeros((pp, v, width), np.int32)
+    mask = np.zeros((pp, v, width), np.float32)
+    occ: Dict[int, list] = {l: [] for l in range(lo, hi)}
+    for r in range(pp):
+        for c in range(v):
+            ls = own[r][c]
+            pad = ls[-1] if ls else min(max(chunks[r][c][0], lo), hi - 1)
+            for j in range(width):
+                idx[r, c, j] = (ls[j] if j < len(ls) else pad) - lo
+                if j < len(ls):
+                    mask[r, c, j] = 1.0
+                    occ[ls[j]].append((r, c, j))
+    flag = mask.copy() if kind == "moe_layers" else np.zeros_like(mask)
+    return KindSlots(kind=kind, idx=idx, mask=mask, moe_flag=flag,
+                     occurrences=tuple(tuple(occ[l]) for l in range(lo, hi)))
 
 
 def chunked_partition(spec: ModelSpec, pp: int, *, schedule: str = "1f1b",
@@ -162,31 +200,19 @@ def chunked_partition(spec: ModelSpec, pp: int, *, schedule: str = "1f1b",
     chunks = rank_chunk_layers(spec, pp, schedule=schedule, n_chunks=v)
     placement = schedule_placement(schedule, pp, v)
     n_dense = spec.n_layers - spec.n_moe_layers()
-    l_max = max(len(ls) for row in chunks for ls in row)
-    idx = np.zeros((pp, v, l_max), np.int32)
-    mask = np.zeros((pp, v, l_max), np.float32)
-    moe_flag = np.zeros((pp, v, l_max), np.float32)
+    bounds = zip(KINDS, (0, n_dense), (n_dense, spec.n_layers))
+    slots = tuple(_kind_slots(kind, lo, hi, chunks)
+                  for kind, lo, hi in bounds if hi > lo)
     first = np.zeros((pp, v), np.float32)
     last = np.zeros((pp, v), np.float32)
-    occ: Dict[int, list] = {l: [] for l in range(spec.n_layers)}
     for r in range(pp):
         for c in range(v):
-            ls = chunks[r][c]
             first[r, c] = float(placement[r][c] == 0)
             last[r, c] = float(placement[r][c] == g - 1)
-            for j in range(l_max):
-                l = ls[j] if j < len(ls) else ls[-1]  # pads repeat a layer
-                idx[r, c, j] = l
-                if j < len(ls):
-                    mask[r, c, j] = 1.0
-                    moe_flag[r, c, j] = float(l >= n_dense)
-                    occ[l].append((r, c, j))
     return ChunkedPartition(
         pp=pp, n_chunks=v, n_stages=g, n_layers=spec.n_layers,
         n_dense=n_dense, schedule=schedule, chunks=chunks,
-        placement=placement, l_max=l_max, idx=idx, mask=mask,
-        moe_flag=moe_flag, first_flag=first, last_flag=last,
-        occurrences=tuple(tuple(occ[l]) for l in range(spec.n_layers)))
+        placement=placement, slots=slots, first_flag=first, last_flag=last)
 
 
 # ---------------------------------------------------------------------------
@@ -304,33 +330,16 @@ def stack_pipeline_params(params: PyTree, spec: ModelSpec, pp: int, *,
                           n_chunks: int = 1) -> PyTree:
     """Model params → chunk-stacked layout for the schedule.
 
-    layers: union slot structure, leaves (pp, n_chunks, l_max, ...); pad
-    slots repeat a real layer of the chunk (masked to identity at apply
-    time) and the unused kind of a mixed dense/MoE slot holds a
-    clipped-gather copy (never selected, so it receives exactly zero
-    gradient).  embed/final_norm/head: (pp, ...) rows, zero except on ranks
-    whose chunks own them (under dualpipe rank 0 and rank pp-1 each own an
-    embedding *and* a head copy).
+    layers: one stack per layer kind the model has (``KINDS``, the Model's
+    groups), leaves (pp, n_chunks, l_kind, ...); pad slots repeat a layer
+    of the kind (masked to identity at apply time, so they receive
+    exactly zero gradient).  embed/final_norm/head: (pp, ...) rows, zero
+    except on ranks whose chunks own them (under dualpipe rank 0 and rank
+    pp-1 each own an embedding *and* a head copy).
     """
     part = chunked_partition(spec, pp, schedule=schedule, n_chunks=n_chunks)
-    nd = part.n_dense
-    dense = params.get("dense_layers") or {}
-    moe = params.get("moe_layers") or {}
-    idx = part.idx
-    idx_d = np.clip(idx, 0, max(nd - 1, 0))
-    idx_m = np.clip(idx - nd, 0, max(part.n_layers - nd - 1, 0))
-
-    layers: Dict[str, Any] = {}
-    for k in dense:
-        if k in moe:
-            layers[k] = jax.tree.map(
-                lambda a, b: _take_layers(jnp.concatenate([a, b], axis=0), idx),
-                dense[k], moe[k])
-        else:
-            layers[k] = jax.tree.map(lambda a: _take_layers(a, idx_d), dense[k])
-    for k in moe:
-        if k not in dense:
-            layers[k] = jax.tree.map(lambda a: _take_layers(a, idx_m), moe[k])
+    layers = {k.kind: jax.tree.map(lambda a: _take_layers(a, k.idx),
+                                   params[k.kind]) for k in part.slots}
 
     has_first = part.first_flag.max(axis=1) > 0        # (pp,) rank owns chunk 0
     has_last = part.last_flag.max(axis=1) > 0
@@ -366,25 +375,15 @@ def unstack_pipeline_grads(gstack: PyTree, params: PyTree, spec: ModelSpec,
     rows are summed across ranks (rows on non-owning ranks are exactly
     zero: their outputs are never selected, so no gradient flows there)."""
     part = chunked_partition(spec, pp, schedule=schedule, n_chunks=n_chunks)
-    nd = part.n_dense
-    occ = part.occurrences
-    r_idx = np.asarray([[o[0] for o in occ[l]] for l in range(part.n_layers)])
-    c_idx = np.asarray([[o[1] for o in occ[l]] for l in range(part.n_layers)])
-    s_idx = np.asarray([[o[2] for o in occ[l]] for l in range(part.n_layers)])
-
-    def gather(leaf: jnp.ndarray) -> jnp.ndarray:
-        # (n_layers, n_occurrences, ...) summed over occurrences
-        return leaf[r_idx, c_idx, s_idx].sum(axis=1)
-
-    dense = params.get("dense_layers") or {}
-    moe = params.get("moe_layers") or {}
-    out: Dict[str, Any] = {"dense_layers": {}, "moe_layers": {}}
-    for k in dense:
-        out["dense_layers"][k] = jax.tree.map(
-            lambda a: gather(a)[:nd], gstack["layers"][k])
-    for k in moe:
-        out["moe_layers"][k] = jax.tree.map(
-            lambda a: gather(a)[nd:], gstack["layers"][k])
+    out: Dict[str, Any] = {kind: {} for kind in KINDS}
+    for k in part.slots:
+        r_idx, c_idx, s_idx = (np.asarray([[o[i] for o in occ]
+                                           for occ in k.occurrences])
+                               for i in range(3))
+        # (layers of the kind, occurrences, ...) summed over occurrences
+        out[k.kind] = jax.tree.map(
+            lambda a: a[r_idx, c_idx, s_idx].sum(axis=1),
+            gstack["layers"][k.kind])
     out["embed"] = {"w": gstack["embed"]["w"].sum(axis=0)}
     out["final_norm"] = {"scale": gstack["final_norm"]["scale"].sum(axis=0)}
     if "head" in params:
@@ -393,7 +392,7 @@ def unstack_pipeline_grads(gstack: PyTree, params: PyTree, spec: ModelSpec,
 
 
 # ---------------------------------------------------------------------------
-# SPMD stage apply (union slots, masked) — the executor's layer stack
+# SPMD stage apply (one kind's slots, masked) — the executor's layer stacks
 # ---------------------------------------------------------------------------
 
 def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
@@ -402,13 +401,13 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
                 sp: bool = False, ep: int = 1,
                 dp_axes: Tuple[str, ...] = ()
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One union layer slot: returns (x, aux, counts), ``counts`` the
-    MoE layer's int32 ``[routed, kept]`` assignments (``moe_forward``),
-    zero on dense and pad slots.  ``mask`` (scalar f32) turns pad slots
-    into the identity; ``moe_flag`` selects the MoE vs dense-MLP branch
-    when the model mixes kinds (only the selected branch receives
-    gradient).  The slot's two halves run under the ``attention`` and
-    ``mlp`` scopes (``repro.scopes``).
+    """One layer slot of one kind (its weights hold ``mlp`` or ``moe``):
+    returns (x, aux, counts), ``counts`` the MoE layer's int32 ``[routed,
+    kept]`` assignments (``moe_forward``), zero on dense and pad slots.
+    ``mask`` (scalar f32) turns pad slots into the identity; ``moe_flag``
+    weighs the MoE output, aux and counts (``KindSlots``).  The slot's two
+    halves run under the ``attention`` and ``mlp`` scopes
+    (``repro.scopes``).
 
     ``tp_axis`` (the executor's 'model' mesh axis) switches on manual
     Megatron TP: ``spec`` must then be the TP-local view
@@ -483,8 +482,7 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
     with jax.named_scope(S.MLP):
         h2 = B.rmsnorm(p["ln2"], x, spec.norm_eps, gemma_style=gemma,
                        backend=backend)
-        has_mlp, has_moe = "mlp" in p, "moe" in p
-        if has_moe:
+        if "moe" in p:
             out = moe_forward(p["moe"], spec, h2,
                               capacity_factor=opts.capacity_factor,
                               router_impl=opts.router_impl,
@@ -493,15 +491,11 @@ def _slot_apply(p: PyTree, spec: ModelSpec, opts: ModelOptions,
                               sp_axis=tp_axis if sp else None,
                               ep=ep, ep_axis=tp_axis if ep > 1 else None,
                               dp_axes=dp_axes, backend=backend)
-            sel = moe_flag.astype(x.dtype)
-            delta = out.y * sel
-            if has_mlp:
-                delta = delta + tpg(mlp_apply(p["mlp"], spec,
-                                              tpf(h2))) * (1 - sel)
+            delta = out.y * moe_flag.astype(x.dtype)
             aux = out.aux_loss * moe_flag * mask
             counts = jnp.stack([out.routed, out.kept]) * (
                 moe_flag * mask > 0.5).astype(jnp.int32)
-        elif has_mlp:
+        elif "mlp" in p:
             delta = tpg(mlp_apply(p["mlp"], spec, tpf(h2)))
         else:
             delta = jnp.zeros_like(x)
@@ -518,10 +512,9 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
                          remat: bool = True,
                          dp_axes: Tuple[str, ...] = ()
                          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Scan this stage's l_max union slots; returns (x, aux, counts),
+    """Scan one kind's slots of this chunk; returns (x, aux, counts),
     ``counts`` the MoE assignments ``[routed, kept]`` summed over them.
-    ``layers_p`` leaves are (l_max, ...); ``mask``/``moe_flag`` are
-    (l_max,).  With ``tp_axis`` the slots run manual TP; with ``sp``
+    ``layers_p`` leaves are (l, ...); ``mask``/``moe_flag`` are (l,).  With ``tp_axis`` the slots run manual TP; with ``sp``
     additionally Megatron sequence parallelism — ``x`` is then the
     seq-sharded residual; with ``ep`` the MoE slots dispatch
     expert-parallel over the same axis; ``dp_axes`` combines the MoE
@@ -547,4 +540,23 @@ def pipeline_stage_apply(layers_p: PyTree, spec: ModelSpec,
     with jax.named_scope(S.LAYER_SCAN):
         (x, aux, counts), _ = jax.lax.scan(body, init,
                                            (layers_p, mask, moe_flag))
+    return x, aux, counts
+
+
+def chunk_layers_apply(layers_p: Dict[str, PyTree], spec: ModelSpec,
+                       opts: ModelOptions, x: jnp.ndarray,
+                       positions: jnp.ndarray, masks: Dict[str, jnp.ndarray],
+                       flags: Dict[str, jnp.ndarray], **kw
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A chunk's layers: one ``pipeline_stage_apply`` scan per kind stack
+    (``layers_p``, ``masks`` and ``flags`` keyed by ``KINDS``), in model
+    order, so each slot computes and holds only its own kind; the aux
+    losses and counts summed."""
+    aux = counts = None
+    for kind in KINDS:
+        if kind in layers_p:
+            x, a, c = pipeline_stage_apply(layers_p[kind], spec, opts, x,
+                                           positions, masks[kind],
+                                           flags[kind], **kw)
+            aux, counts = (a, c) if aux is None else (aux + a, counts + c)
     return x, aux, counts

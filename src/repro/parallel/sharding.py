@@ -2,7 +2,8 @@
 
 Implements the paper's §3 partitioning on the TPU mesh:
   * Megatron-TP of attention & dense MLP  → ``model`` axis
-  * MLA: W^UQ/W^UK/W^UV/W^O split, W^DQ/W^DKV/W^QR/W^KR replicated (§3.2)
+  * MLA: W^UQ/W^UK/W^UV/W^O split, W^DQ/W^DKV/W^QR/W^KR replicated (§3.2);
+    without a query LoRA, W^Q split by heads
   * EP: routed experts sharded on the expert dim; shared expert replicated
     (§3.3); ETP=1 → expert matrices unsplit internally
   * ZeRO (§4): optimizer state (os), gradients (os+g), parameters
@@ -31,6 +32,7 @@ _ATTN_RULES = {
     "w_dq": ("embed", None), "w_uq": (None, "qkv"), "w_qr": (None, "qkv"),
     "w_dkv": ("embed", None), "w_uk": (None, "qkv"), "w_uv": (None, "qkv"),
     "w_kr": ("embed", None), "w_o": ("qkv", "embed"),
+    "w_q": ("embed", "qkv"),
 }
 _SSM_RULES = {
     "w_r": ("embed", "ff"), "w_k": ("embed", "ff"), "w_v": ("embed", "ff"),

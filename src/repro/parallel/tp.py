@@ -308,6 +308,11 @@ def check_ep_supported(spec: ModelSpec, tp: int, ep: int, *,
             f"{spec.name}: ep={ep} != tp={tp}; the executor's a2a dispatch "
             f"group is the whole 'model' axis, so EP degree is tied to it "
             f"(grouped sub-axis a2a stays estimator-only)")
+    if spec.moe.held != spec.moe.n_routed:
+        raise NotImplementedError(
+            f"{spec.name}: a layer that holds {spec.moe.held} of "
+            f"{spec.moe.n_routed} experts runs without the exchange; its "
+            f"all-to-all dispatch over the held share is not built")
     if spec.moe.n_routed % ep:
         raise ValueError(
             f"{spec.name}: ep={ep} does not divide n_routed="

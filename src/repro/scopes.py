@@ -16,8 +16,8 @@ per layer and per phase:
 * the layers below, one per op; the innermost wins where they nest: a
   slot's ``attention`` and ``mlp`` sit inside ``layer_scan`` (the scan
   over a chunk's layer slots, which slices each slot's weights and stacks
-  what the backward reads and the slots' gradients), the MoE scopes
-  inside ``mlp``; ``stage_stack`` copies the weights into the executor's
+  what the backward reads and the slots' gradients), ``mla.towers``
+  inside ``attention``, the MoE scopes inside ``mlp``; ``stage_stack`` copies the weights into the executor's
   chunk-stacked layout at the step's start and the gradients out of it.
 
 The names are fixed, whatever the configuration.  ``scope_map`` reads
@@ -38,17 +38,19 @@ TICKS = (TICK_F, TICK_B, TICK_W)
 EMBED = "embed"              # token embedding (vocab-parallel under TP)
 LAYER_SCAN = "layer_scan"    # the scan over slots: weight slices, stacking
 ATTENTION = "attention"      # ln1, projections, flash or MLA, TP f/g
+MLA_TOWERS = "mla.towers"    # MLA's query projection, KV latent, K/V, rope
 MLP = "mlp"                  # ln2, the dense MLP, the FFN residual
 MOE_ROUTE = "moe.route"      # router, aux, dispatch, all-to-alls, combine
-MOE_EXPERTS = "moe.experts"  # the grouped expert FFN, the shared expert
+MOE_EXPERTS = "moe.experts"  # the grouped expert FFN over the routed experts
+MOE_SHARED = "moe.shared"    # the shared experts, every token
 HEAD = "head"                # final norm, logits, cross-entropy
 GRAD_ACCUM = "grad_accum"    # fp32 adds of each microbatch's gradient
 GRAD_SYNC = "grad_sync"      # post-loop gradient psums, ZeRO reduce-scatter
 OPTIMIZER = "optimizer"      # mean over microbatches, AdamW, ZeRO pins
 STAGE_STACK = "stage_stack"  # weights into the stacked stage layout, grads out
 
-MODEL_LAYERS = (EMBED, LAYER_SCAN, ATTENTION, MLP, MOE_ROUTE, MOE_EXPERTS,
-                HEAD)
+MODEL_LAYERS = (EMBED, LAYER_SCAN, ATTENTION, MLA_TOWERS, MLP, MOE_ROUTE,
+                MOE_EXPERTS, MOE_SHARED, HEAD)
 LAYERS = MODEL_LAYERS + (GRAD_ACCUM, GRAD_SYNC, OPTIMIZER, STAGE_STACK)
 
 FORWARD, REPLAY, BACKWARD = "forward", "replay", "backward"

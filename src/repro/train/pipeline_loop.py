@@ -24,9 +24,9 @@ axis.  Arguments:
 One SPMD program (``shard_map``, fully manual over every mesh axis): every
 device holds one rank's slice of the chunk-stacked parameters
 (``models.pipeline.stack_pipeline_params``, leaves
-``(pp, n_chunks, l_max, ...)``) — and, with a 'model' axis, its 1/tp TP
-shard of them (``parallel.sharding.pipeline_stage_specs``: Megatron
-head/column splits for attention and MLPs, expert-ff (ETP) splits for MoE,
+``(pp, n_chunks, l, ...)`` per layer kind) — and, with a 'model' axis,
+its 1/tp TP shard of them (``parallel.sharding.pipeline_stage_specs``:
+Megatron head/column splits for attention and MLPs, expert-ff (ETP) splits for MoE,
 vocab rows/columns for embedding/head) — and runs the same tick loop; rank
 identity is ``lax.axis_index('pipe')``.  What happens at tick t — forward
 or backward of which microbatch on which local chunk, and where boundary
@@ -152,7 +152,7 @@ across the 'data'(+'pod') axes the collectives run over.
 
 Semantics match ``train.loop.make_train_step``: fp32 gradient accumulation
 across microbatches, mean over n_micro, one AdamW update, loss metric
-ce + 0.01·aux per microbatch.  ``TrainState`` keeps the pp=1 layout — grads
+ce + aux_coef·aux per microbatch (``ModelSpec.aux_coef``).  ``TrainState`` keeps the pp=1 layout — grads
 are unstacked back before the update — so optimizer, checkpointing and the
 pp=1 path are untouched.  All four schedules reproduce the pp=1 step's
 loss and post-update params to bf16-accumulation tolerance at
@@ -198,7 +198,7 @@ from repro.models import backend as B
 from repro.models.layers import embed_apply
 from repro.models.model import Model
 from repro.models.pipeline import (check_pipeline_supported,
-                                   chunked_partition, pipeline_stage_apply,
+                                   chunk_layers_apply, chunked_partition,
                                    stack_pipeline_params,
                                    unstack_pipeline_grads)
 from repro.optim.adamw import TrainState, adamw_update
@@ -329,8 +329,10 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
     SS = tab.s_slots
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     gemma = spec.name.startswith("gemma")
-    masks_all = jnp.asarray(part.mask)              # (S, V, l_max)
-    flags_all = jnp.asarray(part.moe_flag)
+    # per layer kind (S, V, l): the slots of each kind stack
+    masks_all = {k.kind: jnp.asarray(k.mask) for k in part.slots}
+    flags_all = {k.kind: jnp.asarray(k.moe_flag) for k in part.slots}
+    aux_coef = spec.aux_coef
     first_all = jnp.asarray(part.first_flag)        # (S, V)
     last_all = jnp.asarray(part.last_flag)
     zb = schedule == "zb1p"
@@ -371,14 +373,15 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
     def _psum(x, axes):
         return jax.lax.psum(x, axes) if axes else x
 
-    def _run(stacked: PyTree, slot_masks: jnp.ndarray,
-             slot_flags: jnp.ndarray, firsts: jnp.ndarray,
+    def _run(stacked: PyTree, slot_masks: Dict[str, jnp.ndarray],
+             slot_flags: Dict[str, jnp.ndarray], firsts: jnp.ndarray,
              lasts: jnp.ndarray, toks: jnp.ndarray,
              mmask: Optional[jnp.ndarray], gdims: Optional[PyTree] = None):
         """shard_map body: returns (chunk-stacked fp32 grads, loss_sum)."""
         d = jax.lax.axis_index("pipe")
         p = jax.tree.map(lambda a: jnp.squeeze(a, 0), stacked)
-        smask, sflag = slot_masks[0], slot_flags[0]     # (V, l_max) local
+        local = lambda t: jax.tree.map(lambda a: a[0], t)
+        smask, sflag = local(slot_masks), local(slot_flags)  # (V, l) each
         first_l, last_l = firsts[0], lasts[0]           # (V,) local
         _, b_loc, s = toks.shape
         s_loc = s // tp if sp else s      # SP: boundary tensors seq-sharded
@@ -410,7 +413,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
 
         def chunk_fn(pl, ps, x_recv, tok, mm, c, remat=True):
             """Uniform per-chunk program: embed (selected when the chunk is
-            the first model chunk), the chunk's union slots, head + local CE
+            the first model chunk), the chunk's kind stacks, head + local CE
             sum (meaningful on the last model chunk, zero-cotangent
             elsewhere).  Under TP the embedding is row-sharded and the
             logits column-sharded on 'model' (vocab-parallel CE); under SP
@@ -420,7 +423,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             ``remat=False`` (zb1p's split backward) bypasses the slot
             checkpointing so each half of the B/W split replays the chunk
             exactly once.  Also returns the slots' MoE ``[routed, kept]``
-            counts (``pipeline_stage_apply``)."""
+            counts (``chunk_layers_apply``)."""
             with jax.named_scope(scopes.EMBED):
                 if tp_axis:
                     x0 = embed_tp(ps["embed"]["w"], tok, axis=tp_axis,
@@ -430,9 +433,12 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                                      h=spec.h)
                 x = jnp.where(first_l[c] > 0.5, x0, x_recv)
             positions = jnp.broadcast_to(jnp.arange(s)[None], (b_loc, s))
-            y, aux, counts = pipeline_stage_apply(
-                pl, spec_run, opts, x, positions, smask[c], sflag[c],
-                tp_axis, sp=sp, ep=ep, remat=remat, dp_axes=data_axes)
+            y, aux, counts = chunk_layers_apply(
+                pl, spec_run, opts, x, positions,
+                {k: m[c] for k, m in smask.items()},
+                {k: f[c] for k, f in sflag.items()},
+                tp_axis=tp_axis, sp=sp, ep=ep, remat=remat,
+                dp_axes=data_axes)
             with jax.named_scope(scopes.HEAD):
                 z = B.rmsnorm(ps["final_norm"], y, spec.norm_eps,
                               gemma_style=gemma,
@@ -496,12 +502,12 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             # output cotangents: the boundary grad ``dy`` (zeroed on the
             # last model chunk, whose ``y`` has no consumer), the CE mean
             # over the microbatch's target tokens (nonzero only on the
-            # last chunk) and the 0.01 aux weight (aux is the
+            # last chunk) and the aux weight (aux is the
             # whole-microbatch value on every data shard; its backward
             # hands each shard 1/data_size of the load-balance cotangent,
             # and the grads are psummed over the data axes below)
             grads = vjp_fn((jnp.where(last, jnp.zeros((), dy.dtype), dy),
-                            lastc / n_tok, jnp.float32(0.01)))
+                            lastc / n_tok, jnp.float32(aux_coef)))
             return grads, (lastc * _psum(ce, data_axes) / n_tok,
                            lastc * aux, jnp.where(last, cnt_c, 0),
                            last.astype(jnp.int32))
@@ -714,20 +720,26 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                 # boundary operators carry the cross-shard sums in their
                 # backward rules) and must NOT be psummed — that would scale
                 # it by tp.
-                lay = dict(g["layers"])
-                if sp:
-                    for k in ("ln1", "ln2"):
-                        lay[k] = jax.lax.psum(lay[k], tp_axis)
-                if "moe" in lay:
-                    lay["moe"] = dict(
-                        lay["moe"],
-                        router=jax.lax.psum(lay["moe"]["router"], tp_axis))
-                if sp and spec.attention == AttentionKind.MLA:
-                    attn_g = dict(lay["attn"])
-                    for k in ("w_dq", "w_dkv", "w_kr", "q_norm", "kv_norm"):
-                        attn_g[k] = jax.lax.psum(attn_g[k], tp_axis)
-                    lay["attn"] = attn_g
-                g = dict(g, layers=lay)
+                def complete(lay):
+                    lay = dict(lay)
+                    if sp:
+                        for k in ("ln1", "ln2"):
+                            lay[k] = jax.lax.psum(lay[k], tp_axis)
+                    if "moe" in lay:
+                        lay["moe"] = dict(
+                            lay["moe"],
+                            router=jax.lax.psum(lay["moe"]["router"],
+                                                tp_axis))
+                    if sp and spec.attention == AttentionKind.MLA:
+                        attn_g = dict(lay["attn"])
+                        for k in ("w_dq", "w_dkv", "w_kr", "q_norm",
+                                  "kv_norm"):
+                            if k in attn_g:
+                                attn_g[k] = jax.lax.psum(attn_g[k], tp_axis)
+                        lay["attn"] = attn_g
+                    return lay
+                g = dict(g, layers={kind: complete(lay) for kind, lay
+                                    in g["layers"].items()})
                 if sp:
                     g = dict(g, final_norm=jax.lax.psum(g["final_norm"],
                                                         tp_axis))
@@ -741,7 +753,7 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
                     g, gdims_g)
             else:
                 g = jax.tree.map(lambda a: _psum(a, data_axes)[None], g)
-        loss_sum = jax.lax.psum(loss + 0.01 * aux_acc, "pipe")
+        loss_sum = jax.lax.psum(loss + aux_coef * aux_acc, "pipe")
         # the MoE counts of every chunk forward: each pipe rank holds other
         # layers, each data shard other samples and, where the tokens are
         # sharded over 'model' (SP, EP), each model shard other tokens
@@ -809,9 +821,10 @@ def make_pipeline_train_step(model: Model, cfg: TrainConfig, mesh: Mesh, *,
             return _run(stacked_l, masks_l, flags_l, firsts_l, lasts_l,
                         toks_l, rest[0] if rest else None, gdims=gdims)
 
+        slot_spec = {k: P("pipe", None, None) for k in masks_all}
         g_st, loss_sum, counts, fused = shard_map(
             inner, mesh=mesh,
-            in_specs=(stage_specs, P("pipe", None, None), P("pipe", None, None),
+            in_specs=(stage_specs, slot_spec, slot_spec,
                       P("pipe", None), P("pipe", None)) + mspecs,
             out_specs=(stage_specs, P(), P(), P()),
         )(stacked, masks_all, flags_all, first_all, last_all, *margs)

@@ -43,3 +43,20 @@ def test_active_params_moe():
     olmoe = get_spec("olmoe-1b-7b")
     # OLMoE: ~1.3B active of ~6.9B total
     assert 0.9e9 < olmoe.active_params() < 1.7e9
+
+
+def test_deepseek_v2_lite_published_counts():
+    """DeepSeek-V2-Lite: 15.7 B total, 2.4 B active (DeepSeek-V2 report,
+    appendix B), with MLA priced without a query LoRA: W^Q (2048 x 16 x
+    192) in place of W^DQ, W^UQ, W^QR and the query norm.  The report's
+    active count leaves out the input embedding, a lookup: 2.45 B, which
+    it gives as 2.4."""
+    spec = get_spec("deepseek-v2-lite")
+    assert spec.attn_params_per_layer(include_qk_norm=False) == (
+        2048 * 16 * 192 + 2048 * 512 + 2048 * 64 + 512 * 16 * 128 * 2
+        + 16 * 128 * 2048)
+    assert spec.norm_params_per_layer() == 2 * 2048 + 512
+    total = spec.total_params()
+    assert round(total / 1e8) / 10 == 15.7, total
+    active = spec.active_params() - spec.embedding_params()
+    assert math.floor(active / 1e8) / 10 == 2.4, active

@@ -154,14 +154,37 @@ def test_scopes_in_dense_step(dense):
     assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
 
 
+# the scopes of mechanisms olmoe does not have: latent attention and
+# shared experts
+NOT_OLMOE = {S.MLA_TOWERS, S.MOE_SHARED}
+
+
 def test_scopes_in_moe_step(moe):
     facts = scope_facts(moe[0])
-    assert set(facts["found"]) >= set(S.LAYERS) - {S.GRAD_SYNC} | {
-        S.TICK_B}
+    assert set(facts["found"]) >= set(S.LAYERS) - {S.GRAD_SYNC} \
+        - NOT_OLMOE | {S.TICK_B}
+    assert not NOT_OLMOE & set(facts["found"])
     assert_no_forward_tick(facts)
     assert facts["b_replay"] and facts["b_backward"]
     scoped, total = facts["matmuls"]
     assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
+
+
+def test_scopes_in_mla_moe_step():
+    # DeepSeek-V2-Lite's smoke: latent attention's towers and the shared
+    # experts have scopes of their own; C = round(64 * 2 / 8 * 1.5) = 24
+    # slots, 8-aligned for the grouped-matmul kernel
+    text, metrics, spec = build_step("deepseek-v2-lite",
+                                     capacity_factor=1.5)
+    facts = scope_facts(text)
+    assert set(facts["found"]) >= set(S.LAYERS) - {S.GRAD_SYNC} | {
+        S.TICK_B}
+    scoped, total = facts["matmuls"]
+    assert total > 0 and scoped >= 0.95 * total, facts["matmuls"]
+    # the held experts' share of the assignments: 3 MoE layers, 2
+    # microbatches of 64 tokens, top-2, of which those to experts 0-3 of 8
+    every = spec.n_moe_layers() * N_MICRO * SEQ * spec.moe.n_active
+    assert 0 < metrics["moe_kept"] <= metrics["moe_routed"] < every
 
 
 def test_weight_tick_scope_under_zb1p():
@@ -295,7 +318,7 @@ def four():
 
 def test_scopes_in_ep_step(four):
     facts = four["facts"]
-    assert set(facts["found"]) >= set(S.LAYERS) | {S.TICK_B}
+    assert set(facts["found"]) >= set(S.LAYERS) - NOT_OLMOE | {S.TICK_B}
     assert_no_forward_tick(facts)
     assert facts["b_replay"] and facts["b_backward"]
     scoped, total = facts["matmuls"]
@@ -331,5 +354,5 @@ def test_kept_matches_numpy_count_ep2(four):
 
 def test_scope_names_fixed():
     names = S.TICKS + S.LAYERS
-    assert len(set(names)) == len(names) == 14
+    assert len(set(names)) == len(names) == 16
     assert all(re.fullmatch(r"[a-z_]+(\.[A-Za-z_]+)?", n) for n in names)

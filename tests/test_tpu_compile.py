@@ -79,7 +79,10 @@ def test_flash_attention_compiles_for_v5e(one_chip, s, n_h, dq, dv):
 @pytest.mark.parametrize("E, C, h, f", [
     (64, 640, 2048, 1024),    # olmoe-1b-7b, 4096 tokens top-8 at cf 1.25
     (64, 1280, 2048, 1024),   # the same at 8192 tokens
-], ids=["olmoe-c640", "olmoe-c1280"])
+    # deepseek-v2-lite: the 8 experts held of 64, 4096 tokens top-6 at cf
+    # 1.25 (C = 480, a row block that is 8- but not 128-aligned)
+    (8, 480, 2048, 1408),
+], ids=["olmoe-c640", "olmoe-c1280", "dsv2lite-c480"])
 def test_grouped_mlp_compiles_for_v5e(one_chip, monkeypatch, E, C, h, f):
     """The backend's grouped SwiGLU forward — ``_gmm_block``'s tiling and
     three GEMM kernels — lowered natively (the CPU process would pick
