@@ -117,7 +117,7 @@ def bench_flash(smoke: bool) -> List[Dict[str, Any]]:
         rows.append(_row(
             f"attn.pallas.{shape}",
             _time(lambda: ops.flash_attention(q, k, v, scale=scale,
-                                              block_q=bq, block_k=bq)),
+                                              block_q=bq, block_k=bq)[0]),
             f"act_bytes={flash_bytes} tile_vmem={tile:.0f}KiB"))
         from repro.models.attention import chunked_attention
         rows.append(_row(

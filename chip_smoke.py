@@ -184,11 +184,21 @@ def kernels_phase(sz: Sizes, seed: int) -> None:
     for tag, (n_h, dq, dv) in (("flash gqa", sz.gqa), ("flash mla", sz.mla)):
         s = sz.attn_seq
         q, k = normal((1, s, n_h, dq)), normal((1, s, n_h, dq))
-        v = normal((1, s, n_h, dv))
+        v, do = normal((1, s, n_h, dv)), normal((1, s, n_h, dv))
         scale = dq ** -0.5
-        _assert_close(f"{tag} s={s} n_h={n_h} dq={dq} dv={dv}",
-                      K.flash_attention(q, k, v, scale=scale),
-                      R.flash_attention_ref(q, k, v, scale=scale))
+        shape = f"s={s} n_h={n_h} dq={dq} dv={dv}"
+        o, lse = K.flash_attention(q, k, v, scale=scale)
+        want, vjp = jax.vjp(
+            lambda *a: R.flash_attention_ref(*a, scale=scale), q, k, v)
+        _assert_close(f"{tag} {shape}", o, want)
+        # gradients head by head: the first query's dq is zero
+        per_head = lambda x: jnp.swapaxes(x, 1, 2).reshape(n_h, -1)
+        for name, got, ref in zip(
+                ("dq", "dk", "dv"),
+                K.flash_attention_bwd(q, k, v, o, lse, do, scale=scale),
+                vjp(do)):
+            _assert_close(f"{tag} {name} {shape}", per_head(got),
+                          per_head(ref))
 
     E, C, hd, f = sz.gmm
     lhs, rhs = normal((E * C, hd)), normal((E, hd, f), hd ** -0.5)

@@ -32,7 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .mla_attention import flash_attention_pallas
+from .mla_attention import flash_attention_bwd_pallas, flash_attention_pallas
 from .moe_gmm import gmm_pallas, pad_groups
 from .rmsnorm import rmsnorm_pallas
 
@@ -75,10 +75,26 @@ def _flash_attention_jit(q, k, v, *, scale, causal, block_q, block_k,
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = None):
+    """(o, lse): the output and its rows' float32 log-sum-exp."""
     interpret = default_interpret() if interpret is None else interpret
     return _flash_attention_jit(q, k, v, scale=scale, causal=causal,
                                 block_q=block_q, block_k=block_k,
                                 interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _flash_attention_bwd_jit(q, k, v, o, lse, do, *, scale, interpret):
+    return flash_attention_bwd_pallas(q, k, v, o, lse, do, scale=scale,
+                                      interpret=interpret)
+
+
+def flash_attention_bwd(q, k, v, o, lse, do, *, scale: float,
+                        interpret: bool = None):
+    """Causal attention's (dq, dk, dv) from ``flash_attention``'s inputs,
+    its (o, lse) and the output's cotangent ``do``."""
+    interpret = default_interpret() if interpret is None else interpret
+    return _flash_attention_bwd_jit(q, k, v, o, lse, do, scale=scale,
+                                    interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
@@ -95,5 +111,5 @@ def gmm(lhs, rhs, expert_map, *, block_m: int = 128, block_n: int = 128,
                     interpret=interpret)
 
 
-__all__ = ["rmsnorm", "flash_attention", "gmm", "pad_groups",
-           "default_interpret"]
+__all__ = ["rmsnorm", "flash_attention", "flash_attention_bwd", "gmm",
+           "pad_groups", "default_interpret"]

@@ -27,13 +27,14 @@ executor with *no* kernel-side collectives): operands arrive pre-sharded.
   no host-side regrouping (``pad_groups``) in the traced path.
 
 Autodiff contract: ``pl.pallas_call`` has no general transpose rule, so
-each pallas op is a ``jax.custom_vjp`` — forward through the kernel,
-backward by re-deriving the vjp of the jnp oracle (``kernels.ref``) from
-the saved *inputs*.  That is exactly the flash recompute story: nothing
-O(s²) is resident between forward and backward; the score matrix only
-materialises transiently inside one layer's backward.  It also pins the
-gradients to the reference path, so the executor equivalence harnesses
-compare like with like.
+each pallas op is a ``jax.custom_vjp``.  Attention's backward is a kernel
+of its own (``kernels.ops.flash_attention_bwd``): the forward saves q, k,
+v, its output and the rows' float32 log-sum-exp, and the backward
+recomputes the probabilities block by block from them, so nothing O(s²)
+exists in either pass.  ``rmsnorm`` and the grouped MLP still take their
+backward from the vjp of a jnp oracle (``kernels.ref.rmsnorm_ref``,
+``_grouped_mlp_ref``) re-derived from the saved *inputs*, which pins
+their gradients to the reference path.
 """
 
 from __future__ import annotations
@@ -146,24 +147,21 @@ def rmsnorm(p, x, eps: float = 1e-6, *, gemma_style: bool = False,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _pallas_attention(scale: float, q, k, v):
     from repro.kernels import ops as K
-    return K.flash_attention(q, k, v, scale=scale, causal=True)
+    return K.flash_attention(q, k, v, scale=scale, causal=True)[0]
 
 
 def _pallas_attention_fwd(scale, q, k, v):
-    return _pallas_attention(scale, q, k, v), (q, k, v)
+    from repro.kernels import ops as K
+    o, lse = K.flash_attention(q, k, v, scale=scale, causal=True)
+    return o, (q, k, v, o, lse)
 
 
 def _pallas_attention_bwd(scale, res, g):
-    # Recompute-style backward through the jnp oracle: only q/k/v were
-    # saved, so the s² score matrix exists transiently inside this vjp and
-    # is never resident across the forward/backward gap — the accounting
-    # core.activations prices as attn_impl="flash".
-    from repro.kernels.ref import flash_attention_ref
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: flash_attention_ref(q_, k_, v_, scale=scale,
-                                               causal=True), q, k, v)
-    return vjp(g)
+    # The flash backward kernel recomputes the probabilities block by block
+    # from the saved row statistics: no s² tensor exists at any point —
+    # the accounting core.activations prices as attn_impl="flash".
+    from repro.kernels import ops as K
+    return K.flash_attention_bwd(*res, g, scale=scale)
 
 
 _pallas_attention.defvjp(_pallas_attention_fwd, _pallas_attention_bwd)

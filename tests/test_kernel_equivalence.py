@@ -28,9 +28,9 @@ import pytest
 
 from repro.models import backend as B
 
-# bf16 tolerances: the pallas forwards accumulate in fp32 but inputs and
-# outputs are bf16 (~3 decimal digits); backwards go through the jnp
-# oracle's vjp on both paths, so grads agree tighter than forwards.
+# bf16 tolerances: the pallas kernels accumulate in fp32 but inputs and
+# outputs are bf16 (~3 decimal digits); the attention backward kernel also
+# rounds P and dS to bf16 before their matmuls, as FlashAttention-2 does.
 ATOL_FWD, ATOL_GRAD = 5e-2, 5e-2
 
 
@@ -94,10 +94,41 @@ def test_mla_attention_equivalence_bf16_dq_neq_dv():
     k = jax.random.normal(kk, (b, s, nh, dq), jnp.bfloat16)
     v = jax.random.normal(kv, (b, s, nh, dv), jnp.bfloat16)
     scale = dq ** -0.5
-    y_r = B.mla_attention(q, k, v, scale=scale, impl="naive")
-    y_p = B.mla_attention(q, k, v, scale=scale, impl="pallas")
+
+    def f(q_, k_, v_, impl):
+        y = B.mla_attention(q_, k_, v_, scale=scale, impl=impl)
+        return jnp.sum(y.astype(jnp.float32) ** 2), y
+
+    (_, y_r), g_r = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, "naive")
+    (_, y_p), g_p = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, "pallas")
     assert y_p.shape == (b, s, nh, dv)
     _assert_close("mla fwd dq!=dv", y_p, y_r, ATOL_FWD)
+    for name, gp, gr in zip("qkv", g_p, g_r):
+        assert gp.shape == gr.shape
+        _assert_close(f"mla d{name} dq!=dv", gp, gr, ATOL_GRAD)
+
+
+def test_pallas_attention_gradient_holds_no_s2_buffer():
+    """The gradient of the pallas attention, lowered at s 1024 (interpret
+    mode, so the kernels' own bodies are in the program too), holds no
+    array whose two trailing dims are (s, s); the naive path's does."""
+    import re
+    b, s, nh, dq, dv = 1, 1024, 2, 192, 128
+    q = jax.ShapeDtypeStruct((b, s, nh, dq), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, s, nh, dv), jnp.bfloat16)
+    s2 = re.compile(rf"tensor<(?:\d+x)*{s}x{s}x\w+>")
+
+    def lowered(impl):
+        def loss(q_, k_, v_):
+            y = B.attention(q_, k_, v_, scale=dq ** -0.5, impl=impl)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, v).as_text()
+
+    assert s2.search(lowered("naive"))
+    assert not s2.search(lowered("pallas"))
 
 
 def test_grouped_mlp_equivalence_bf16():

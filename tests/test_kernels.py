@@ -62,8 +62,8 @@ def test_flash_matches_ref(b, s, nh, dq, dv, dtype):
     k = _rand(k2, (b, s, nh, dq), dtype)
     v = _rand(k3, (b, s, nh, dv), dtype)
     scale = dq ** -0.5
-    got = ops.flash_attention(q, k, v, scale=scale, block_q=64, block_k=64,
-                              interpret=True)
+    got, _ = ops.flash_attention(q, k, v, scale=scale, block_q=64,
+                                 block_k=64, interpret=True)
     want = ref.flash_attention_ref(q, k, v, scale=scale)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -76,8 +76,8 @@ def test_flash_non_causal():
     q = _rand(k1, (1, 128, 2, 64), jnp.float32)
     k = _rand(k2, (1, 128, 2, 64), jnp.float32)
     v = _rand(k3, (1, 128, 2, 64), jnp.float32)
-    got = ops.flash_attention(q, k, v, scale=0.125, causal=False,
-                              interpret=True)
+    got, _ = ops.flash_attention(q, k, v, scale=0.125, causal=False,
+                                 interpret=True)
     want = ref.flash_attention_ref(q, k, v, scale=0.125, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -89,8 +89,8 @@ def test_flash_block_sweep(block):
     q = _rand(k1, (1, 257, 2, 64), jnp.float32)   # deliberately ragged
     k = _rand(k2, (1, 257, 2, 64), jnp.float32)
     v = _rand(k3, (1, 257, 2, 64), jnp.float32)
-    got = ops.flash_attention(q, k, v, scale=0.125, block_q=block,
-                              block_k=block, interpret=True)
+    got, _ = ops.flash_attention(q, k, v, scale=0.125, block_q=block,
+                                 block_k=block, interpret=True)
     want = ref.flash_attention_ref(q, k, v, scale=0.125)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -103,11 +103,76 @@ def test_flash_matches_model_chunked_attention():
     q = _rand(k1, (2, 96, 2, 64), jnp.float32)
     k = _rand(k2, (2, 96, 2, 64), jnp.float32)
     v = _rand(k3, (2, 96, 2, 64), jnp.float32)
-    a = ops.flash_attention(q, k, v, scale=0.125, interpret=True)
+    a, _ = ops.flash_attention(q, k, v, scale=0.125, interpret=True)
     b = chunked_attention(q, k, v, 0.125, block=32)
     c = ref.flash_attention_ref(q, k, v, scale=0.125)
     np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(b), np.asarray(c), atol=2e-5, rtol=2e-5)
+
+
+def test_flash_lse_is_the_rows_logsumexp():
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(10), 3)
+    b, s, nh, dq, dv = 2, 200, 2, 192, 128
+    q = _rand(k1, (b, s, nh, dq), jnp.float32)
+    k = _rand(k2, (b, s, nh, dq), jnp.float32)
+    v = _rand(k3, (b, s, nh, dv), jnp.float32)
+    _, lse = ops.flash_attention(q, k, v, scale=dq ** -0.5, interpret=True)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * dq ** -0.5
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    assert lse.shape == (b, nh, s) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(jax.nn.logsumexp(scores, -1)),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("b,s,nh,dq,dv", [
+    (2, 256, 2, 128, 128),      # GQA heads after replication
+    (1, 256, 2, 192, 128),      # MLA: d_h + d_hr = 192 for q/k, d_v = 128
+    (1, 200, 2, 192, 128),      # padded to 256
+    (2, 100, 1, 64, 32),        # under one 128 block: padded to 104
+    (1, 128, 2, 64, 64),        # block sweep (q x k): 128 x 128 once,
+    (1, 384, 1, 64, 64),        # 128 x 128, three k blocks,
+    (1, 1024, 1, 128, 64),      # 256 x 512, two k blocks
+], ids=["gqa", "mla", "mla-pad", "short-pad", "q128k128x1", "q128k128x3",
+        "q256k512x2"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_bwd_matches_ref_vjp(b, s, nh, dq, dv, dtype):
+    """dq, dk, dv of the backward kernel, from the forward's (o, lse),
+    against ``jax.vjp`` of the naive oracle."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = _rand(k1, (b, s, nh, dq), dtype)
+    k = _rand(k2, (b, s, nh, dq), dtype)
+    v = _rand(k3, (b, s, nh, dv), dtype)
+    do = _rand(k4, (b, s, nh, dv), dtype)
+    scale = dq ** -0.5
+    o, lse = ops.flash_attention(q, k, v, scale=scale, interpret=True)
+    got = ops.flash_attention_bwd(q, k, v, o, lse, do, scale=scale,
+                                  interpret=True)
+    _, vjp = jax.vjp(lambda *a: ref.flash_attention_ref(*a, scale=scale),
+                     q, k, v)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, g, w in zip(("dq", "dk", "dv"), got, vjp(do)):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(np.asarray(g, np.float32), w,
+                                   atol=tol * np.abs(w).max(), rtol=tol,
+                                   err_msg=name)
+
+
+def test_flash_bwd_plan():
+    """Blocks from (s, dq, dv) and VMEM alone: the largest k block that
+    divides the padded sequence, 256-row q blocks where 256 divides it,
+    and a refusal where a head cannot stay in VMEM."""
+    from repro.kernels.mla_attention import _BWD_VMEM_BUDGET, _bwd_plan
+    assert _bwd_plan(4096, 192, 128, 2)[:3] == (4096, 256, 512)
+    assert _bwd_plan(2048, 128, 128, 2)[:3] == (2048, 256, 512)
+    assert _bwd_plan(200, 192, 128, 2)[:3] == (256, 256, 256)
+    assert _bwd_plan(384, 64, 64, 2)[:3] == (384, 128, 128)
+    assert _bwd_plan(640, 128, 128, 4)[:3] == (640, 128, 128)
+    assert _bwd_plan(100, 64, 32, 2)[:3] == (104, 104, 104)
+    assert _bwd_plan(4096, 192, 128, 2)[3] <= _BWD_VMEM_BUDGET
+    with pytest.raises(ValueError, match="does not fit"):
+        _bwd_plan(2 ** 17, 192, 128, 2)
 
 
 # ---------------------------------------------------------------------------

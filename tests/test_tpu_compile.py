@@ -21,7 +21,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops as K
-from repro.kernels.mla_attention import flash_attention_pallas
+from repro.kernels.mla_attention import (flash_attention_bwd_pallas,
+                                         flash_attention_pallas)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.models import backend as B
 
@@ -68,11 +69,18 @@ def test_rmsnorm_compiles_for_v5e(one_chip):
     (4096, 16, 192, 128),     # MLA: d_h + d_hr = 192 for q/k, d_v = 128
 ], ids=["gqa-s2048", "gqa-s4096", "mla-s4096"])
 def test_flash_attention_compiles_for_v5e(one_chip, s, n_h, dq, dv):
+    """The forward, and the backward from the forward's (o, lse)."""
     q = jax.ShapeDtypeStruct((1, s, n_h, dq), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((1, s, n_h, dv), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, n_h, s), jnp.float32, sharding=one_chip)
     text = _compiled_text(
         lambda q_, k_, v_: flash_attention_pallas(
             q_, k_, v_, scale=dq ** -0.5, interpret=False), q, q, v)
+    assert "tpu_custom_call" in text
+    text = _compiled_text(
+        lambda q_, k_, v_, o_, lse_, do_: flash_attention_bwd_pallas(
+            q_, k_, v_, o_, lse_, do_, scale=dq ** -0.5, interpret=False),
+        q, q, v, v, lse, v)
     assert "tpu_custom_call" in text
 
 
@@ -143,4 +151,5 @@ def test_executor_scopes_survive_tpu_fusion(topo, monkeypatch):
     assert {S.ATTENTION, S.MOE_EXPERTS, S.HEAD, S.OPTIMIZER} <= layers
     kernels = {re.sub(r"\.\d+$", "", n) for n in launched
                if n.startswith("_")}
-    assert {"_flash_attention_jit", "_gmm_jit"} <= kernels
+    assert {"_flash_attention_jit", "_flash_attention_bwd_jit",
+            "_gmm_jit"} <= kernels
